@@ -11,6 +11,8 @@ import argparse
 import sys
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 SECTIONS = ("memory", "throughput", "internals", "quality", "sensitivity",
             "kernel", "roofline", "tiering", "decode", "prefill")
 
@@ -19,6 +21,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    use_compile_cache()
     wanted = args.only.split(",") if args.only else list(SECTIONS)
 
     print("name,us_per_call,derived")

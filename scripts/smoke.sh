@@ -113,9 +113,7 @@ test -n "$HTTP_PORT" || { cat /tmp/forkkv_http.log; exit 1; }
 HTTP_PORT="$HTTP_PORT" python - <<'PY'
 import os
 import numpy as np
-from repro.launch.serve import build_server
 from repro.serving.frontend import ForkClient
-from repro.serving.sampling import SamplingParams
 
 client = ForkClient(port=int(os.environ["HTTP_PORT"]))
 assert client.healthz()
@@ -135,16 +133,14 @@ for _ in range(2):
     assert events[-1]["finished"] and len(streamed) == 8, events[-1]
     assert streamed == events[-1]["tokens"]
     runs.append(streamed)
-client.close_session(sid)
 assert runs[0] == runs[1], runs
 
-# ...must match the speculation-OFF in-process API token-for-token
-# (greedy ON==OFF parity over HTTP), with the paged path never falling
-# back to gather
-server, _ = build_server("forkkv", max_pages=256, admission="fairshare")
-sess = server.session(ctx, adapter_id=0)
-expected = sess.fork(1, instr,
-                     SamplingParams(max_new_tokens=8)).result().tokens
+# ...must match a speculation-OFF fork of the same session on the same
+# server token-for-token (greedy ON==OFF parity; only the server process
+# touches JAX), with the paged path never falling back to gather
+expected = client.fork(sid, instr, adapter_id=1, max_new_tokens=8,
+                       speculate=False)["tokens"]
+client.close_session(sid)
 assert runs[0] == expected, (runs[0], expected)
 m = client.metrics()
 assert m["fallback_gather_calls"] == 0, m["fallback_gather_calls"]

@@ -1,9 +1,11 @@
 """Public entry points for ResidualAttention.
 
-``residual_attention(...)`` dispatches between the Pallas kernel (TPU target,
-validated on CPU via ``interpret=True``) and the pure-jnp oracle in
-:mod:`repro.kernels.ref`.  The jitted model code calls these wrappers so the
-backend can be swapped with one flag.
+``residual_attention(...)`` and the paged dispatchers choose between the
+Pallas kernels and the pure-jnp oracle in :mod:`repro.kernels.ref`.  On a
+TPU the kernels are compiled by Mosaic (the paged grids are compile-tested
+for a v5e in ``tests/test_tpu_compile.py``); elsewhere they run in
+interpret mode.  The jitted model code calls these wrappers so the backend
+can be swapped with one flag.
 """
 from __future__ import annotations
 
@@ -49,20 +51,24 @@ def get_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
-def _resolve_interpret(interpret):
-    if interpret is not None:
-        return interpret
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in interpret mode: off a TPU, or
+    when ``pallas-interpret`` forces it."""
     if _FORCE_INTERPRET:
         return True
     import jax
     return jax.default_backend() != "tpu"
 
 
+def _resolve_interpret(interpret):
+    return interpret_mode() if interpret is None else interpret
+
+
 def residual_attention(q, k_base, v_base, k_res, v_res, b_k, b_v, sin, cos,
                        *, qpos, kv_len, window: int = 0, causal: bool = True,
                        scale: Optional[float] = None,
                        backend: Optional[str] = None,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: Optional[bool] = None) -> jnp.ndarray:
     """Attention over a disaggregated KV cache.  Shapes as in ref.py."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -72,6 +78,7 @@ def residual_attention(q, k_base, v_base, k_res, v_res, b_k, b_v, sin, cos,
             q, k_base, v_base, k_res, v_res, b_k, b_v, sin, cos,
             qpos=qpos, kv_len=kv_len, window=window, causal=causal,
             scale=scale)
+    interpret = _resolve_interpret(interpret)
     if q.shape[1] == 1:   # decode fast path
         out = ra.residual_attention_decode(
             q[:, 0], k_base, v_base, k_res, v_res, b_k, b_v, sin, cos,
@@ -88,7 +95,7 @@ def paged_residual_attention(q, kb_pool, vb_pool, kr_pool, vr_pool, b_k,
                              window: int = 0,
                              rope_theta: float = 10_000.0,
                              use_rope: bool = True,
-                             kb_scale=None, vb_scale=None,
+                             kb_scale=None, vb_scale=None, layer: int = 0,
                              backend: Optional[str] = None,
                              interpret: Optional[bool] = None) -> jnp.ndarray:
     """Decode attention over paged pools + block tables (DESIGN.md §12).
@@ -107,6 +114,8 @@ def paged_residual_attention(q, kb_pool, vb_pool, kr_pool, vr_pool, b_k,
 
     Pass ``kr_pool=None`` (with ``vr_pool``/``b_k``/``b_v``/``bt_r`` also
     None) for the base-only variant — unified caches or no-LoRA requests.
+    Pools are stacked over layers, (L, ...), and read at the static
+    ``layer``.
     ``kv_len`` counts ALL valid tokens incl. the one just written; the
     query row sits at position ``kv_len - 1``.  ``window > 0`` restricts
     attention to the trailing ``window`` positions (SWA) and skips the
@@ -119,17 +128,19 @@ def paged_residual_attention(q, kb_pool, vb_pool, kr_pool, vr_pool, b_k,
         return ref_mod.paged_residual_attention_ref(
             q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v, bt_b, bt_r,
             kv_len, scale=scale, window=window, rope_theta=rope_theta,
-            use_rope=use_rope, kb_scale=kb_scale, vb_scale=vb_scale)
+            use_rope=use_rope, kb_scale=kb_scale, vb_scale=vb_scale,
+            layer=layer)
     interpret = _resolve_interpret(interpret)
     if kr_pool is None:
         return pra.paged_attention_decode_base(
             q, kb_pool, vb_pool, bt_b, kv_len, scale=scale, window=window,
-            kb_scale=kb_scale, vb_scale=vb_scale, interpret=interpret)
+            kb_scale=kb_scale, vb_scale=vb_scale, layer=layer,
+            interpret=interpret)
     return pra.paged_residual_attention_decode(
         q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v, bt_b, bt_r,
         kv_len, scale=scale, window=window, rope_theta=rope_theta,
         use_rope=use_rope, kb_scale=kb_scale, vb_scale=vb_scale,
-        interpret=interpret)
+        layer=layer, interpret=interpret)
 
 
 def paged_residual_attention_prefill(q, kb_pool, vb_pool, kr_pool, vr_pool,
@@ -139,6 +150,7 @@ def paged_residual_attention_prefill(q, kb_pool, vb_pool, kr_pool, vr_pool,
                                      rope_theta: float = 10_000.0,
                                      use_rope: bool = True,
                                      kb_scale=None, vb_scale=None,
+                                     layer: int = 0,
                                      backend: Optional[str] = None,
                                      interpret: Optional[bool] = None
                                      ) -> jnp.ndarray:
@@ -162,18 +174,18 @@ def paged_residual_attention_prefill(q, kb_pool, vb_pool, kr_pool, vr_pool,
             q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v, bt_b, bt_r,
             start, kv_len, scale=scale, window=window,
             rope_theta=rope_theta, use_rope=use_rope, kb_scale=kb_scale,
-            vb_scale=vb_scale)
+            vb_scale=vb_scale, layer=layer)
     interpret = _resolve_interpret(interpret)
     if kr_pool is None:
         return pra.paged_attention_prefill_base(
             q, kb_pool, vb_pool, bt_b, start, kv_len, scale=scale,
             window=window, kb_scale=kb_scale, vb_scale=vb_scale,
-            interpret=interpret)
+            layer=layer, interpret=interpret)
     return pra.paged_residual_attention_prefill(
         q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v, bt_b, bt_r,
         start, kv_len, scale=scale, window=window, rope_theta=rope_theta,
         use_rope=use_rope, kb_scale=kb_scale, vb_scale=vb_scale,
-        interpret=interpret)
+        layer=layer, interpret=interpret)
 
 
 def paged_residual_attention_mixed(q, kb_pool, vb_pool, kr_pool, vr_pool,
@@ -183,6 +195,7 @@ def paged_residual_attention_mixed(q, kb_pool, vb_pool, kr_pool, vr_pool,
                                    rope_theta: float = 10_000.0,
                                    use_rope: bool = True,
                                    kb_scale=None, vb_scale=None,
+                                   layer: int = 0,
                                    backend: Optional[str] = None,
                                    interpret: Optional[bool] = None
                                    ) -> jnp.ndarray:
@@ -203,15 +216,15 @@ def paged_residual_attention_mixed(q, kb_pool, vb_pool, kr_pool, vr_pool,
             q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v, bt_b, bt_r,
             start, q_len, kv_len, scale=scale, window=window,
             rope_theta=rope_theta, use_rope=use_rope, kb_scale=kb_scale,
-            vb_scale=vb_scale)
+            vb_scale=vb_scale, layer=layer)
     interpret = _resolve_interpret(interpret)
     if kr_pool is None:
         return pra.paged_attention_mixed_base(
             q, kb_pool, vb_pool, bt_b, start, q_len, kv_len, scale=scale,
             window=window, kb_scale=kb_scale, vb_scale=vb_scale,
-            interpret=interpret)
+            layer=layer, interpret=interpret)
     return pra.paged_residual_attention_mixed(
         q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v, bt_b, bt_r,
         start, q_len, kv_len, scale=scale, window=window,
         rope_theta=rope_theta, use_rope=use_rope, kb_scale=kb_scale,
-        vb_scale=vb_scale, interpret=interpret)
+        vb_scale=vb_scale, layer=layer, interpret=interpret)

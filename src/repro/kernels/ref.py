@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import attention as attn_lib
 from repro.core import rope as rope_lib
+from repro.kernels import paged_residual_attention as pra
 
 
 def reconstruct(k_base, v_base, k_res, v_res, b_k, b_v, sin, cos):
@@ -41,27 +42,31 @@ def reconstruct(k_base, v_base, k_res, v_res, b_k, b_v, sin, cos):
 
 def _gather_paged_kv(q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v,
                      bt_b, bt_r, *, rope_theta: float, use_rope: bool,
-                     kb_scale=None, vb_scale=None):
+                     kb_scale=None, vb_scale=None, layer: int = 0):
     """Gather block-table pages into contiguous (B, Sk, ...) views and, for
     the disaggregated layout, reconstruct full K/V.  Shared by the paged
-    decode and prefill oracles.  ``kb_scale``/``vb_scale`` ((P, page,
-    Hkv) f32, or None) mark the base pools as int8: pages are dequantized
-    right after the gather, BEFORE reconstruction, mirroring the kernels'
-    in-VMEM dequant (DESIGN.md §18)."""
+    decode and prefill oracles.  Pools are in the kernels' storage layouts
+    (``paged_residual_attention`` module docstring).  ``kb_scale``/
+    ``vb_scale`` ((L, P, Hkv, 1, page) f32, or None) mark the base pools as
+    int8: pages are dequantized right after the gather, BEFORE
+    reconstruction, mirroring the kernels' in-VMEM dequant (DESIGN.md
+    §18).  The (L, ...) pools are read at ``layer``."""
     bsz, d = q.shape[0], q.shape[-1]
-    page, hkv = kb_pool.shape[1], kb_pool.shape[2]
-    sk = bt_b.shape[1] * page
-    kb = kb_pool[bt_b].reshape(bsz, sk, hkv, d)
-    vb = vb_pool[bt_b].reshape(bsz, sk, hkv, d)
+    kb_pool, vb_pool, kb_scale, vb_scale = (
+        None if x is None else x[layer]
+        for x in (kb_pool, vb_pool, kb_scale, vb_scale))
+    kb = pra.gather_base(kb_pool, bt_b)
+    vb = pra.gather_base(vb_pool, bt_b)
+    sk = kb.shape[1]
     if kb_scale is not None:
-        ks = kb_scale[bt_b].reshape(bsz, sk, hkv)[..., None]
-        vs = vb_scale[bt_b].reshape(bsz, sk, hkv)[..., None]
+        ks = pra.gather_scale(kb_scale, bt_b)[..., None]
+        vs = pra.gather_scale(vb_scale, bt_b)[..., None]
         kb = (kb.astype(jnp.float32) * ks).astype(q.dtype)
         vb = (vb.astype(jnp.float32) * vs).astype(q.dtype)
     if kr_pool is None:
         return kb, vb
-    kr = kr_pool[bt_r].reshape(bsz, sk, -1)
-    vr = vr_pool[bt_r].reshape(bsz, sk, -1)
+    kr = pra.gather_res(kr_pool[layer], bt_r, b_k.shape[1])
+    vr = pra.gather_res(vr_pool[layer], bt_r, b_k.shape[1])
     kpos = jnp.broadcast_to(jnp.arange(sk), (bsz, sk))
     if use_rope:
         sin, cos = rope_lib.rope_sincos(kpos, d, rope_theta)
@@ -89,7 +94,8 @@ def paged_residual_attention_ref(q, kb_pool, vb_pool, kr_pool, vr_pool,
                                  rope_theta: float = 10_000.0,
                                  use_rope: bool = True,
                                  kb_scale=None,
-                                 vb_scale=None) -> jnp.ndarray:
+                                 vb_scale=None,
+                                 layer: int = 0) -> jnp.ndarray:
     """XLA mirror of the paged decode kernels: gather the block-table pages
     into contiguous views, then run the dense oracle.  Same interface as
     :func:`repro.kernels.paged_residual_attention.
@@ -101,19 +107,21 @@ def paged_residual_attention_ref(q, kb_pool, vb_pool, kr_pool, vr_pool,
     even this fallback's HBM traffic scales with actual ``kv_len`` rather
     than the engine-wide ``smax`` (DESIGN.md §12).
 
-    q: (B, Hq, D); kb/vb: (P, page, Hkv, D); kr/vr: (Pr, page, R) or None;
+    q: (B, Hq, D); kb/vb: (L, P, Hkv, page, D); kr/vr: packed residual
+    pools (``paged_residual_attention`` module docstring) or None, read
+    at ``layer``;
     b_k/b_v: (B, R, Hkv*D) or None; bt_b/bt_r: (B, W); kv_len: (B,) —
     the query row sits at position ``kv_len - 1``; ``window > 0`` keeps
     only the trailing ``window`` positions (SWA).  Returns (B, Hq, D).
     """
     bsz, hq, d = q.shape
-    sk = bt_b.shape[1] * kb_pool.shape[1]
+    sk = bt_b.shape[1] * kb_pool.shape[-2]
     if scale is None:
         scale = d ** -0.5
     k, v = _gather_paged_kv(q, kb_pool, vb_pool, kr_pool, vr_pool, b_k,
                             b_v, bt_b, bt_r, rope_theta=rope_theta,
                             use_rope=use_rope, kb_scale=kb_scale,
-                            vb_scale=vb_scale)
+                            vb_scale=vb_scale, layer=layer)
     kp = jnp.arange(sk)[None, None, None, :]
     # the query sits at kv_len - 1, so the causal bound and the validity
     # bound coincide: one mask term covers both
@@ -131,8 +139,8 @@ def paged_residual_attention_prefill_ref(q, kb_pool, vb_pool, kr_pool,
                                          window: int = 0,
                                          rope_theta: float = 10_000.0,
                                          use_rope: bool = True,
-                                         kb_scale=None, vb_scale=None
-                                         ) -> jnp.ndarray:
+                                         kb_scale=None, vb_scale=None,
+                                         layer: int = 0) -> jnp.ndarray:
     """XLA mirror of the paged chunked-prefill kernels (DESIGN.md §13):
     gather block-table pages into contiguous views, reconstruct (disagg)
     and attend with the causal-within-chunk + window + validity mask.
@@ -143,13 +151,13 @@ def paged_residual_attention_prefill_ref(q, kb_pool, vb_pool, kr_pool,
     Returns (B, chunk, Hq, D).
     """
     bsz, sq, hq, d = q.shape
-    sk = bt_b.shape[1] * kb_pool.shape[1]
+    sk = bt_b.shape[1] * kb_pool.shape[-2]
     if scale is None:
         scale = d ** -0.5
     k, v = _gather_paged_kv(q, kb_pool, vb_pool, kr_pool, vr_pool, b_k,
                             b_v, bt_b, bt_r, rope_theta=rope_theta,
                             use_rope=use_rope, kb_scale=kb_scale,
-                            vb_scale=vb_scale)
+                            vb_scale=vb_scale, layer=layer)
     qpos = start[:, None] + jnp.arange(sq)[None]          # (B, Sq)
     qp = qpos[:, None, :, None]
     kp = jnp.arange(sk)[None, None, None, :]
@@ -166,8 +174,8 @@ def paged_residual_attention_mixed_ref(q, kb_pool, vb_pool, kr_pool,
                                        window: int = 0,
                                        rope_theta: float = 10_000.0,
                                        use_rope: bool = True,
-                                       kb_scale=None, vb_scale=None
-                                       ) -> jnp.ndarray:
+                                       kb_scale=None, vb_scale=None,
+                                       layer: int = 0) -> jnp.ndarray:
     """XLA mirror of the unified mixed prefill/decode kernels
     (DESIGN.md §14): the prefill oracle generalized with a per-row
     ``q_len`` — rows past it are masked out AND explicitly zeroed in the
@@ -180,13 +188,13 @@ def paged_residual_attention_mixed_ref(q, kb_pool, vb_pool, kr_pool,
     variant.  Returns (B, chunk, Hq, D).
     """
     bsz, sq, hq, d = q.shape
-    sk = bt_b.shape[1] * kb_pool.shape[1]
+    sk = bt_b.shape[1] * kb_pool.shape[-2]
     if scale is None:
         scale = d ** -0.5
     k, v = _gather_paged_kv(q, kb_pool, vb_pool, kr_pool, vr_pool, b_k,
                             b_v, bt_b, bt_r, rope_theta=rope_theta,
                             use_rope=use_rope, kb_scale=kb_scale,
-                            vb_scale=vb_scale)
+                            vb_scale=vb_scale, layer=layer)
     rowidx = jnp.arange(sq)[None]                       # (1, Sq)
     rowvalid = rowidx < q_len[:, None]                  # (B, Sq)
     qpos = start[:, None] + rowidx

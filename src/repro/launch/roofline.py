@@ -88,14 +88,9 @@ def roofline_terms(flops: float, bytes_accessed: float, coll_bytes: float,
 
 
 def hlo_cost_analysis(compiled) -> Dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: some
-    return one dict, others a one-element list of dicts.  Shape-only
-    normalization: an empty/None result becomes ``{}`` (as the seed's
-    ``or {}`` did) while exceptions propagate to the caller."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+    """``compiled.cost_analysis()`` as a dict (an empty/None result becomes
+    ``{}``; exceptions propagate to the caller)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def analyze_compiled(lowered, compiled, chips: int,

@@ -3,6 +3,11 @@
   PYTHONPATH=src python -m repro.launch.serve --mode forkkv \
       --workflow react --workflows 2 --agents 3
 
+``--model`` picks the served configuration from
+``configs.paper_models.SERVE_MODELS``: ``serve-tiny`` (the default, sized
+for the CPU) or ``llama3-8b`` (published widths, 8 of 32 layers, for one
+TPU v5e).
+
 Runs entirely through the session/fork API (``repro.serving.api``): the
 launcher builds a :class:`ForkServer`, the workflow driver pins the shared
 context in an :class:`AgentSession` and forks agents off it.
@@ -16,17 +21,19 @@ import os
 
 import jax
 
-from repro.configs.paper_models import tiny_serving_model
+from repro.configs.paper_models import SERVE_MODELS
 from repro.core.config import ServeConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as tfm
 from repro.serving.api import ForkServer
 from repro.serving.sampling import SamplingParams
 from repro.serving.workflows import WorkflowConfig, WorkflowDriver
 
 
-def build_server(mode: str, *, rank: int = 8, max_pages: int = 512,
-                 max_batch: int = 8, n_adapters: int = 32,
-                 max_pages_per_req: int = 24, seed: int = 0,
+def build_server(mode: str, *, model: str = "serve-tiny", params=None,
+                 lora=None, max_pages: int = 512, max_batch: int = 8,
+                 n_adapters: int = 32, max_pages_per_req: int = 24,
+                 seed: int = 0,
                  host_tier_bytes: int = 0, tier_promote_limit: int = 0,
                  broadcast_fork: bool = False,
                  adaptive_fallback: bool = False,
@@ -50,12 +57,22 @@ def build_server(mode: str, *, rank: int = 8, max_pages: int = 512,
                  kv_codec: str = "identity",
                  disk_tier_bytes: int = 0,
                  persist_dir: str = ""):
-    cfg = tiny_serving_model(rank=rank)
+    """Build a :class:`ForkServer` over ``model`` with random weights from
+    ``seed`` and ``n_adapters`` adapters of the model's LoRA rank
+    (serve-tiny 8, llama3-8b its published 16), or with the given
+    ``params`` and ``lora`` (e.g. shared by several servers in one
+    process; the model then computes in the dtype of ``params``).
+    Returns ``(server, cfg)``."""
+    cfg = SERVE_MODELS[model]()
     if kv_quant != "none":
         cfg = dataclasses.replace(cfg, kv_quant=kv_quant)
-    params = tfm.init_params(cfg, jax.random.PRNGKey(seed))
-    lora = tfm.init_lora_stacks(cfg, jax.random.PRNGKey(seed + 1),
-                                n_adapters=n_adapters)
+    if params is None:
+        params = tfm.init_params(cfg, jax.random.PRNGKey(seed))
+    else:
+        cfg = dataclasses.replace(cfg, dtype=params["embed"].dtype.name)
+    if lora is None:
+        lora = tfm.init_lora_stacks(cfg, jax.random.PRNGKey(seed + 1),
+                                    n_adapters=n_adapters)
     sc = ServeConfig(page_size=16, max_pages=max_pages, max_batch=max_batch,
                      max_prefill_tokens=128, mode=mode,
                      max_pages_per_req=max_pages_per_req,
@@ -101,6 +118,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="forkkv",
                     choices=["forkkv", "prefix", "full_reuse"])
+    ap.add_argument("--model", default="serve-tiny",
+                    choices=sorted(SERVE_MODELS),
+                    help="served configuration (llama3-8b: published "
+                         "widths, 8 of 32 layers, sized for one TPU v5e)")
     ap.add_argument("--workflow", default="react",
                     choices=["react", "mapreduce"])
     ap.add_argument("--workflows", type=int, default=2)
@@ -208,13 +229,14 @@ def main() -> None:
                          "aggregates (TTFT/TPOT p50/p99)")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     weights = []
     for spec in args.tenant_weight:
         name, _, w = spec.partition("=")
         weights.append((name, float(w or 1.0)))
     server, cfg = build_server(
-        args.mode, max_pages=args.max_pages,
+        args.mode, model=args.model, max_pages=args.max_pages,
         host_tier_bytes=args.host_tier_mb << 20,
         tier_promote_limit=args.tier_promote_limit,
         kv_quant=args.kv_quant, kv_codec=args.kv_codec,

@@ -36,6 +36,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import json
+import logging
 import os
 import tempfile
 import time
@@ -55,6 +56,8 @@ from repro.serving.speculate import AdaptiveK, make_proposer
 from repro.serving.tiers import (DiskTier, HostTier, TieredPagePool,
                                  blob_bytes, get_codec, read_blob_file,
                                  write_blob_file)
+
+log = logging.getLogger(__name__)
 
 
 def percentile(vals: Sequence[float], q: float) -> float:
@@ -770,7 +773,10 @@ class Engine:
         step call cannot say which rows' device state survived, so every
         running request fails terminally (``finish_reason="error"``,
         pages reclaimed, nothing committed) and the PUMP SURVIVES —
-        waiting requests admit and run on the next step."""
+        waiting requests admit and run on the next step.  The exception
+        is logged with its traceback: isolation must not make it silent."""
+        log.error("executor error at step %d, failing %d running "
+                  "request(s)", self.steps, len(self.running), exc_info=exc)
         self.exec_errors += 1
         victims = list(self.running)
         for r in victims:

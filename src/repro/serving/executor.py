@@ -1,9 +1,13 @@
 """Paged model executor: jit'd prefill/decode over pooled KV pages.
 
-The pools are jnp arrays of shape (L, num_pages, page_size, ...); requests
-address them through block tables.  In ForkKV mode two pools exist — the
-shared bCache pool and the per-agent rCache pool — and attention runs over
-the disaggregated layout.
+The pools are jnp arrays with a leading layer axis in the paged kernels'
+storage layouts (``kernels/paged_residual_attention.py``: head-major base
+pages, lane-packed residual pages); requests address them through block
+tables of page ids.  In ForkKV mode two pools exist — the shared bCache
+pool and the per-agent rCache pool — and attention runs over the
+disaggregated layout.  Weights and adapters are arguments of every jitted
+step, never constants captured in it, so a compiled step's size does not
+depend on the model's.
 
 Decode AND prefill are page-native (DESIGN.md §12/§13): the jitted steps
 hand the pools and per-request block tables straight to the
@@ -43,6 +47,7 @@ import numpy as np
 
 from repro.core.config import ModelConfig, ServeConfig
 from repro.kernels import ops as kernel_ops
+from repro.kernels import paged_residual_attention as pra
 from repro.models import base
 from repro.models import transformer as tfm
 from repro.serving.sampling import sample_tokens
@@ -56,14 +61,16 @@ def _pow2(n: int) -> int:
 
 
 class Pools(NamedTuple):
-    kb: jnp.ndarray          # (L, Pb, page, Hkv, hd)  base K (RoPE'd)
-    vb: jnp.ndarray          # (L, Pb, page, Hkv, hd)  base V
-    kr: Optional[jnp.ndarray]  # (L, Pr, page, R)      residual K (no RoPE)
+    kb: jnp.ndarray          # (L, Pb, Hkv, page, hd)  base K (RoPE'd)
+    vb: jnp.ndarray          # (L, Pb, Hkv, page, hd)  base V
+    # residual K (no RoPE) / V: (L, ceil(Pr/G), page, G·R), G rank-R pages
+    # packed per lane-dense row (pra.res_group)
+    kr: Optional[jnp.ndarray]
     vr: Optional[jnp.ndarray]
     # int8 bCache pages (ModelConfig.kv_quant == "int8"): per-token-per-head
     # f32 dequant scales, written alongside every kb/vb write.  None on the
     # full-precision path; the rCache is rank-r and stays unquantized.
-    kb_s: Optional[jnp.ndarray] = None   # (L, Pb, page, Hkv)
+    kb_s: Optional[jnp.ndarray] = None   # (L, Pb, Hkv, 1, page)
     vb_s: Optional[jnp.ndarray] = None
 
 
@@ -71,21 +78,51 @@ def make_pools(cfg: ModelConfig, num_pages: int, num_res_pages: int,
                page_size: int, disagg: bool, dtype=None) -> Pools:
     dt = dtype or cfg.activation_dtype
     L, hd = cfg.num_layers, cfg.resolved_head_dim
+    r = cfg.lora.rank
     quant = getattr(cfg, "kv_quant", "none") == "int8"
-    kb = jnp.zeros((L, num_pages, page_size, cfg.num_kv_heads, hd),
+    kb = jnp.zeros((L, num_pages, cfg.num_kv_heads, page_size, hd),
                    jnp.int8 if quant else dt)
     vb = jnp.zeros_like(kb)
     if disagg:
-        kr = jnp.zeros((L, num_res_pages, page_size, cfg.lora.rank), dt)
+        kr = jnp.zeros((L, pra.res_pool_rows(num_res_pages, r), page_size,
+                        pra.res_group(r) * r), dt)
         vr = jnp.zeros_like(kr)
     else:
         kr = vr = None
     kb_s = vb_s = None
     if quant:
-        kb_s = jnp.zeros((L, num_pages, page_size, cfg.num_kv_heads),
+        kb_s = jnp.zeros((L, num_pages, cfg.num_kv_heads, 1, page_size),
                          jnp.float32)
         vb_s = jnp.zeros_like(kb_s)
     return Pools(kb, vb, kr, vr, kb_s, vb_s)
+
+
+def write_tokens(pools: Pools, li: int, wp_b, wp_r, woff, kb_, vb_, ks_,
+                 vs_, kr_, vr_, rank: int) -> Pools:
+    """Scatter new tokens' K/V into layer ``li`` of the pools.
+
+    ``wp_b``/``wp_r``/``woff`` index the tokens (any common leading shape
+    ``T``, broadcastable); ``kb_``/``vb_``: T + (Hkv, hd); ``ks_``/``vs_``:
+    T + (Hkv,) int8 scales or None; ``kr_``/``vr_``: T + (R,) residuals,
+    or None when the pools hold no rCache.  Every index is spelled out
+    down to the head, so each scattered window is one contiguous head_dim
+    row and XLA keeps the pools in the layout the kernels read: a
+    head-slice window makes it relayout (copy) whole pools per layer."""
+    heads = jnp.arange(kb_.shape[-2], dtype=jnp.int32)
+    at = (li, wp_b[..., None], heads, woff[..., None])
+    kb = pools.kb.at[at].set(kb_)
+    vb = pools.vb.at[at].set(vb_)
+    ks, vs = pools.kb_s, pools.vb_s
+    if ks_ is not None:
+        at = (li, wp_b[..., None], heads, 0, woff[..., None])
+        ks = ks.at[at].set(ks_)
+        vs = vs.at[at].set(vs_)
+    kr, vr = pools.kr, pools.vr
+    if kr_ is not None:
+        rows, lanes = pra.res_lane_index(wp_r, rank)
+        kr = kr.at[li, rows, woff[..., None], lanes].set(kr_)
+        vr = vr.at[li, rows, woff[..., None], lanes].set(vr_)
+    return Pools(kb, vb, kr, vr, ks, vs)
 
 
 def pool_bytes(pools: Pools) -> Dict[str, int]:
@@ -134,9 +171,10 @@ class PagedExecutor:
         # ``sampled`` is static: all-greedy batches (the default) compile
         # the seed's pure-argmax body with the sampling math dead-code
         # eliminated; a second variant exists only once sampling is used
-        self._decode = jax.jit(self._decode_fn, donate_argnums=(0,),
+        # weights ride in as arguments 0-1 and the donated pools as 2
+        self._decode = jax.jit(self._decode_fn, donate_argnums=(2,),
                                static_argnames=("sampled",))
-        self._prefill = jax.jit(self._prefill_fn, donate_argnums=(0,),
+        self._prefill = jax.jit(self._prefill_fn, donate_argnums=(2,),
                                 static_argnames=("chunk", "sampled",
                                                  "unified", "verify"))
 
@@ -146,17 +184,19 @@ class PagedExecutor:
         """Device→host copy of whole KV pages (DESIGN.md §10).
 
         ``kind`` selects the pool ("base" → kb/vb, "res" → kr/vr).  Returns
-        one blob per page — ``{"k": (L, page, ...), "v": ...}`` numpy
-        arrays holding the exact bytes, so a later :meth:`import_pages`
-        restores the cache bit-identically.
+        one blob per page — ``{"k": (L, ...), "v": ...}`` numpy arrays
+        holding the exact bytes of one page (head-major base pages, or a
+        residual page's own (page, R) lanes), so a later
+        :meth:`import_pages` restores the cache bit-identically.
         """
         ids = jnp.asarray(list(page_ids), jnp.int32)
         if kind == "base":
-            k, v = self.pools.kb, self.pools.vb
+            karr = np.asarray(self.pools.kb[:, ids])     # (L, n, Hkv, ...)
+            varr = np.asarray(self.pools.vb[:, ids])
         else:
-            k, v = self.pools.kr, self.pools.vr
-        karr = np.asarray(k[:, ids])          # (L, n, page, ...)
-        varr = np.asarray(v[:, ids])
+            r = self.cfg.lora.rank
+            karr = np.asarray(pra.res_pages(self.pools.kr, ids, r))
+            varr = np.asarray(pra.res_pages(self.pools.vr, ids, r))
         # per-page COPIES, not views: each blob must be independently
         # freeable or the HostTier's byte accounting undercounts (a
         # surviving 1-page view would pin the whole n-page export)
@@ -207,10 +247,16 @@ class PagedExecutor:
                         kb=pools.kb.at[:, ids_].set(k_),
                         vb=pools.vb.at[:, ids_].set(v_))
             else:
+                rank = self.cfg.lora.rank
+
                 def fn(pools, ids_, k_, v_):
-                    return pools._replace(
-                        kr=pools.kr.at[:, ids_].set(k_),
-                        vr=pools.vr.at[:, ids_].set(v_))
+                    # (L, n, page, R) blobs into each page's own lanes
+                    rows, lanes = pra.res_lane_index(ids_, rank)
+                    li = jnp.arange(k_.shape[0])[:, None, None, None]
+                    t = jnp.arange(k_.shape[2])[None, None, :, None]
+                    at = (li, rows[None, :, None], t, lanes[None, :, None])
+                    return pools._replace(kr=pools.kr.at[at].set(k_),
+                                          vr=pools.vr.at[at].set(v_))
             self._import_jit[key] = jax.jit(fn, donate_argnums=(0,))
         if quant:
             ks = jnp.asarray(np.stack([b["ks"] for b in blobs], axis=1))
@@ -222,14 +268,12 @@ class PagedExecutor:
                 self.pools, jnp.asarray(ids, jnp.int32), k, v)
 
     # ------------------------------------------------------------ helpers
-    def _layer_params(self, li):
-        return jax.tree_util.tree_map(lambda t: t[li],
-                                      self.params["layers"])
-
-    def _lora_layer(self, li):
-        if self.lora is None:
+    @staticmethod
+    def _layer(tree, li):
+        """Layer ``li`` of a stacked (L, ...) pytree (None passes)."""
+        if tree is None:
             return None
-        return jax.tree_util.tree_map(lambda t: t[li], self.lora)
+        return jax.tree_util.tree_map(lambda t: t[li], tree)
 
     def _project_kv(self, p_l, lora_l, h, sin, cos, adapter_ids):
         cfg = self.cfg
@@ -288,20 +332,34 @@ class PagedExecutor:
         vq, vs = tfm.quantize_kv(vb_)
         return kq, vq, ks, vs
 
-    def _dq_gather(self, pool_l, scale_l, bt, bsz, w):
-        """Legacy gather path under int8: gather pages AND scales, then
-        dequantize the contiguous view (the kernels instead dequantize
-        per page tile in VMEM)."""
-        x = pool_l[bt].astype(jnp.float32) * scale_l[bt][..., None]
-        return x.astype(self.cfg.activation_dtype).reshape(
-            bsz, w, self.cfg.num_kv_heads, -1)
+    def _gather_kv(self, pools: Pools, li, bt_b, bt_r):
+        """Legacy gather path: this layer's block-table pages as
+        contiguous (B, W·page, ...) views — int8 pages dequantized with
+        their scales (the kernels instead dequantize per page tile in
+        VMEM), residuals only for disaggregated pools given ``bt_r``."""
+        kc = pra.gather_base(pools.kb[li], bt_b)
+        vc = pra.gather_base(pools.vb[li], bt_b)
+        if self.kv_quant:
+            dt = self.cfg.activation_dtype
+            kc = (kc.astype(jnp.float32) * pra.gather_scale(
+                pools.kb_s[li], bt_b)[..., None]).astype(dt)
+            vc = (vc.astype(jnp.float32) * pra.gather_scale(
+                pools.vb_s[li], bt_b)[..., None]).astype(dt)
+        if not self.disagg or bt_r is None:
+            return kc, vc, None, None
+        r = self.cfg.lora.rank
+        return (kc, vc, pra.gather_res(pools.kr[li], bt_r, r),
+                pra.gather_res(pools.vr[li], bt_r, r))
 
     # ------------------------------------------------------------- decode
-    def _decode_fn(self, pools: Pools, tokens, kv_len, adapter_ids, bt_b,
-                   bt_r, wpage_b, wpage_r, woff, temps, top_ks, top_ps,
-                   seeds, spos, poison, *, sampled):
+    def _decode_fn(self, params: Params, lora: Optional[Params],
+                   pools: Pools, tokens, kv_len, adapter_ids, bt_b, bt_r,
+                   wpage_b, wpage_r, woff, temps, top_ks, top_ps, seeds,
+                   spos, poison, *, sampled):
         """One decode step for a padded batch.
 
+        params/lora: the model weights and adapter stacks (arguments, so
+        the compiled step is the same for any weights); pools: donated;
         tokens/kv_len/adapter_ids: (B,); bt_*: (B, W) block tables (W is
         the bucketed live width on the paged path, ``max_pages_per_req``
         on the gather path); wpage_*: (B,) page indices to write the new
@@ -320,12 +378,12 @@ class PagedExecutor:
         """
         cfg = self.cfg
         bsz = tokens.shape[0]
-        x = self.params["embed"][tokens][:, None]
+        x = params["embed"][tokens][:, None]
         kmask_pos = None
         new_pools = pools
         for li in range(cfg.num_layers):
-            p_l = self._layer_params(li)
-            lora_l = self._lora_layer(li)
+            p_l = self._layer(params["layers"], li)
+            lora_l = self._layer(lora, li)
             h = base.rms_norm(x, p_l["ln1"], cfg.norm_eps)
             q, sin, cos = tfm._qkv(p_l, h, cfg, lora_l, adapter_ids,
                                    kv_len[:, None])
@@ -333,49 +391,35 @@ class PagedExecutor:
                 p_l, lora_l, h, sin, cos, adapter_ids)
             kb_, vb_, ks_, vs_ = self._maybe_quant(kb_, vb_)
             # write new token
-            kbp = new_pools.kb.at[li, wpage_b, woff].set(kb_[:, 0])
-            vbp = new_pools.vb.at[li, wpage_b, woff].set(vb_[:, 0])
-            if self.kv_quant:
-                ksp = new_pools.kb_s.at[li, wpage_b, woff].set(ks_[:, 0])
-                vsp = new_pools.vb_s.at[li, wpage_b, woff].set(vs_[:, 0])
-            else:
-                ksp, vsp = new_pools.kb_s, new_pools.vb_s
-            if self.disagg:
-                krp = new_pools.kr.at[li, wpage_r, woff].set(kr_[:, 0])
-                vrp = new_pools.vr.at[li, wpage_r, woff].set(vr_[:, 0])
-            else:
-                krp, vrp = new_pools.kr, new_pools.vr
-            new_pools = Pools(kbp, vbp, krp, vrp, ksp, vsp)
+            new_pools = write_tokens(
+                new_pools, li, wpage_b, wpage_r, woff, kb_[:, 0], vb_[:, 0],
+                None if ks_ is None else ks_[:, 0],
+                None if vs_ is None else vs_[:, 0],
+                kr_[:, 0] if self.disagg else None,
+                vr_[:, 0] if self.disagg else None, cfg.lora.rank)
+            kbp, vbp, krp, vrp, ksp, vsp = new_pools
             if self.use_paged:
                 # page-native attention: pools + block tables, no gather
                 attn = kernel_ops.paged_residual_attention(
-                    q[:, 0], kbp[li], vbp[li],
-                    krp[li] if self.disagg else None,
-                    vrp[li] if self.disagg else None,
+                    q[:, 0], kbp, vbp,
+                    krp if self.disagg else None,
+                    vrp if self.disagg else None,
                     bk if self.disagg else None,
                     bv if self.disagg else None,
                     bt_b, bt_r if self.disagg else None, kv_len + 1,
                     scale=cfg.resolved_head_dim ** -0.5,
                     window=cfg.sliding_window,
                     rope_theta=cfg.rope_theta, use_rope=cfg.use_rope,
-                    kb_scale=ksp[li] if self.kv_quant else None,
-                    vb_scale=vsp[li] if self.kv_quant else None)
+                    kb_scale=ksp, vb_scale=vsp, layer=li)
             else:
                 # legacy: gather this request's pages -> contiguous view
                 w = bt_b.shape[1] * self.page
-                if self.kv_quant:
-                    kc = self._dq_gather(kbp[li], ksp[li], bt_b, bsz, w)
-                    vc = self._dq_gather(vbp[li], vsp[li], bt_b, bsz, w)
-                else:
-                    kc = kbp[li][bt_b].reshape(bsz, w, cfg.num_kv_heads, -1)
-                    vc = vbp[li][bt_b].reshape(bsz, w, cfg.num_kv_heads, -1)
+                kc, vc, krc, vrc = self._gather_kv(new_pools, li, bt_b, bt_r)
                 if self.disagg:
-                    krc = krp[li][bt_r].reshape(bsz, w, -1)
-                    vrc = vrp[li][bt_r].reshape(bsz, w, -1)
                     bk_rows = bk.reshape(bsz, cfg.lora.rank, -1)
                     bv_rows = bv.reshape(bsz, cfg.lora.rank, -1)
                 else:
-                    krc = vrc = bk_rows = bv_rows = None
+                    bk_rows = bv_rows = None
                 if kmask_pos is None:
                     kmask_pos = jnp.broadcast_to(jnp.arange(w)[None],
                                                  (bsz, w))
@@ -387,7 +431,7 @@ class PagedExecutor:
             x = x + attn.reshape(bsz, 1, -1) @ p_l["wo"]
             h = base.rms_norm(x, p_l["ln2"], cfg.norm_eps)
             x = x + tfm.ffn(p_l, h, cfg)
-        logits = tfm.unembed(self.params, x, cfg)[:, 0]
+        logits = tfm.unembed(params, x, cfg)[:, 0]
         logits = jnp.where(poison[:, None] > 0, jnp.nan, logits)
         row_ok = jnp.all(jnp.isfinite(logits), axis=-1)
         if sampled:
@@ -446,7 +490,8 @@ class PagedExecutor:
         spos += [0] * pad
         poison += [0] * pad
         self.pools, next_tok, logits, row_ok = self._decode(
-            self.pools, jnp.asarray(tokens, jnp.int32),
+            self.params, self.lora, self.pools,
+            jnp.asarray(tokens, jnp.int32),
             jnp.asarray(kv_len, jnp.int32),
             jnp.asarray(adapter_ids, jnp.int32),
             jnp.asarray(bt_b, jnp.int32), jnp.asarray(bt_r, jnp.int32),
@@ -460,20 +505,18 @@ class PagedExecutor:
 
     def decode_cache_size(self) -> int:
         """Number of compiled decode variants (bucket coverage probe)."""
-        try:
-            return self._decode._cache_size()
-        except Exception:       # pragma: no cover - older jax
-            return -1
+        return self._decode._cache_size()
 
     # ------------------------------------------------------------ prefill
-    def _prefill_fn(self, pools: Pools, tokens, start, n_valid, adapter_ids,
-                    bt_b, bt_r, wpages_b, wpages_r, temps, top_ks, top_ps,
-                    seeds, spos, poison, *, chunk, sampled, unified=False,
+    def _prefill_fn(self, params: Params, lora: Optional[Params],
+                    pools: Pools, tokens, start, n_valid, adapter_ids, bt_b,
+                    bt_r, wpages_b, wpages_r, temps, top_ks, top_ps, seeds,
+                    spos, poison, *, chunk, sampled, unified=False,
                     verify=False):
         """Chunked prefill for a PADDED BATCH of requests.
 
-        tokens: (B, chunk) padded; start: (B,) absolute position of each
-        row's tokens[0]; n_valid: (B,) #real tokens per row (0 for padding
+        params/lora/pools as :meth:`_decode_fn`; tokens: (B, chunk)
+        padded; start: (B,) absolute position of each row's tokens[0]; n_valid: (B,) #real tokens per row (0 for padding
         rows); wpages_*: (B, chunk) page to write each token into (dump
         page where the cache is inherited — CoW: shared pages are never
         written); temps/top_ks/top_ps/seeds/spos: (B,) sampling params for
@@ -508,13 +551,13 @@ class PagedExecutor:
         cfg = self.cfg
         bsz = tokens.shape[0]
         positions = start[:, None] + jnp.arange(chunk)[None]    # (B, chunk)
-        x = self.params["embed"][tokens]                        # (B, chunk, d)
+        x = params["embed"][tokens]                             # (B, chunk, d)
         woff = positions % self.page
         valid = jnp.arange(chunk)[None] < n_valid[:, None]      # (B, chunk)
         new_pools = pools
         for li in range(cfg.num_layers):
-            p_l = self._layer_params(li)
-            lora_l = self._lora_layer(li)
+            p_l = self._layer(params["layers"], li)
+            lora_l = self._layer(lora, li)
             h = base.rms_norm(x, p_l["ln1"], cfg.norm_eps)
             q, sin, cos = tfm._qkv(p_l, h, cfg, lora_l, adapter_ids,
                                    positions)
@@ -523,67 +566,48 @@ class PagedExecutor:
             kb_, vb_, ks_, vs_ = self._maybe_quant(kb_, vb_)
             wp_b = jnp.where(valid, wpages_b, self.dump_page)
             wp_r = jnp.where(valid, wpages_r, self.dump_page_r)
-            kbp = new_pools.kb.at[li, wp_b, woff].set(kb_)
-            vbp = new_pools.vb.at[li, wp_b, woff].set(vb_)
-            if self.kv_quant:
-                ksp = new_pools.kb_s.at[li, wp_b, woff].set(ks_)
-                vsp = new_pools.vb_s.at[li, wp_b, woff].set(vs_)
-            else:
-                ksp, vsp = new_pools.kb_s, new_pools.vb_s
-            if self.disagg:
-                krp = new_pools.kr.at[li, wp_r, woff].set(kr_)
-                vrp = new_pools.vr.at[li, wp_r, woff].set(vr_)
-            else:
-                krp, vrp = new_pools.kr, new_pools.vr
-            new_pools = Pools(kbp, vbp, krp, vrp, ksp, vsp)
+            new_pools = write_tokens(new_pools, li, wp_b, wp_r, woff, kb_,
+                                     vb_, ks_, vs_, kr_, vr_, cfg.lora.rank)
+            kbp, vbp, krp, vrp, ksp, vsp = new_pools
             if self.use_paged and unified:
                 # unified mixed grid (§14): per-row q-length scalar
                 # prefetch — decode rows (n_valid=1) and prefill chunks
                 # attend in ONE launch, padding rows exact-zeroed
                 attn = kernel_ops.paged_residual_attention_mixed(
-                    q, kbp[li], vbp[li],
-                    krp[li] if self.disagg else None,
-                    vrp[li] if self.disagg else None,
+                    q, kbp, vbp,
+                    krp if self.disagg else None,
+                    vrp if self.disagg else None,
                     bk if self.disagg else None,
                     bv if self.disagg else None,
                     bt_b, bt_r if self.disagg else None, start, n_valid,
                     start + n_valid, scale=cfg.resolved_head_dim ** -0.5,
                     window=cfg.sliding_window, rope_theta=cfg.rope_theta,
                     use_rope=cfg.use_rope,
-                    kb_scale=ksp[li] if self.kv_quant else None,
-                    vb_scale=vsp[li] if self.kv_quant else None)
+                    kb_scale=ksp, vb_scale=vsp, layer=li)
             elif self.use_paged:
                 # page-native prefill (§13): the chunk's K/V is already in
                 # the pools — stream KV page by page via the block tables,
                 # causal mask inside the chunk, no gather-to-contiguous
                 attn = kernel_ops.paged_residual_attention_prefill(
-                    q, kbp[li], vbp[li],
-                    krp[li] if self.disagg else None,
-                    vrp[li] if self.disagg else None,
+                    q, kbp, vbp,
+                    krp if self.disagg else None,
+                    vrp if self.disagg else None,
                     bk if self.disagg else None,
                     bv if self.disagg else None,
                     bt_b, bt_r if self.disagg else None, start,
                     start + n_valid, scale=cfg.resolved_head_dim ** -0.5,
                     window=cfg.sliding_window, rope_theta=cfg.rope_theta,
                     use_rope=cfg.use_rope,
-                    kb_scale=ksp[li] if self.kv_quant else None,
-                    vb_scale=vsp[li] if self.kv_quant else None)
+                    kb_scale=ksp, vb_scale=vsp, layer=li)
             else:
                 # legacy: gather every request's pages -> contiguous view
                 w = bt_b.shape[1] * self.page
-                if self.kv_quant:
-                    kc = self._dq_gather(kbp[li], ksp[li], bt_b, bsz, w)
-                    vc = self._dq_gather(vbp[li], vsp[li], bt_b, bsz, w)
-                else:
-                    kc = kbp[li][bt_b].reshape(bsz, w, cfg.num_kv_heads, -1)
-                    vc = vbp[li][bt_b].reshape(bsz, w, cfg.num_kv_heads, -1)
+                kc, vc, krc, vrc = self._gather_kv(new_pools, li, bt_b, bt_r)
                 if self.disagg:
-                    krc = krp[li][bt_r].reshape(bsz, w, -1)
-                    vrc = vrp[li][bt_r].reshape(bsz, w, -1)
                     bk_rows = bk.reshape(bsz, cfg.lora.rank, -1)
                     bv_rows = bv.reshape(bsz, cfg.lora.rank, -1)
                 else:
-                    krc = vrc = bk_rows = bv_rows = None
+                    bk_rows = bv_rows = None
                 kmask_pos = jnp.broadcast_to(jnp.arange(w)[None], (bsz, w))
                 attn = tfm._attend(q, kc, vc, krc, vrc, bk_rows, bv_rows,
                                    kmask_pos, start + n_valid, positions,
@@ -599,7 +623,7 @@ class PagedExecutor:
             # unembed EVERY position once; the last-valid logits are a
             # gather from the same tensor (bit-identical to the x_last
             # path: unembed is a per-position matmul)
-            logits_all = tfm.unembed(self.params, x, cfg)     # (B, chunk, V)
+            logits_all = tfm.unembed(params, x, cfg)          # (B, chunk, V)
             greedy_all = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
             logits = jnp.take_along_axis(
                 logits_all, idx[:, None, None], axis=1)[:, 0]
@@ -610,7 +634,7 @@ class PagedExecutor:
             n_acc = jnp.cumprod(ok.astype(jnp.int32), axis=1).sum(axis=1)
         else:
             x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-            logits = tfm.unembed(self.params, x_last, cfg)[:, 0]   # (B, V)
+            logits = tfm.unembed(params, x_last, cfg)[:, 0]        # (B, V)
         logits = jnp.where(poison[:, None] > 0, jnp.nan, logits)
         row_ok = jnp.all(jnp.isfinite(logits), axis=-1)
         if sampled:
@@ -690,7 +714,8 @@ class PagedExecutor:
         spos += [0] * pad
         poison += [0] * pad
         self.pools, next_tok, logits, row_ok = self._prefill(
-            self.pools, jnp.asarray(toks, jnp.int32),
+            self.params, self.lora, self.pools,
+            jnp.asarray(toks, jnp.int32),
             jnp.asarray(starts, jnp.int32), jnp.asarray(nvalid, jnp.int32),
             jnp.asarray(adapter_ids, jnp.int32),
             jnp.asarray(btb, jnp.int32), jnp.asarray(btr, jnp.int32),
@@ -797,7 +822,8 @@ class PagedExecutor:
         spos += [0] * pad
         poison += [0] * pad
         out = self._prefill(
-            self.pools, jnp.asarray(toks, jnp.int32),
+            self.params, self.lora, self.pools,
+            jnp.asarray(toks, jnp.int32),
             jnp.asarray(starts, jnp.int32), jnp.asarray(nvalid, jnp.int32),
             jnp.asarray(adapter_ids, jnp.int32),
             jnp.asarray(btb, jnp.int32), jnp.asarray(btr, jnp.int32),
@@ -811,7 +837,8 @@ class PagedExecutor:
         return tuple(out[1:])
 
     # ------------------------------------------------- broadcast fork
-    def _prefill_broadcast_fn(self, pools: Pools, tokens, start, n_valid,
+    def _prefill_broadcast_fn(self, params: Params, lora: Params,
+                              pools: Pools, tokens, start, n_valid,
                               adapter_ids, bt_b, wpages_b, wpages_r, *,
                               chunk, n_agents):
         """Beyond-paper broadcast fork (DESIGN.md §9): ONE base-trajectory
@@ -824,13 +851,13 @@ class PagedExecutor:
         """
         cfg = self.cfg
         positions = start + jnp.arange(chunk)
-        x = self.params["embed"][tokens][None]
+        x = params["embed"][tokens][None]
         woff = positions % self.page
         valid = jnp.arange(chunk) < n_valid
         new_pools = pools
         for li in range(cfg.num_layers):
-            p_l = self._layer_params(li)
-            lora_l = self._lora_layer(li)
+            p_l = self._layer(params["layers"], li)
+            lora_l = self._layer(lora, li)
             h = base.rms_norm(x, p_l["ln1"], cfg.norm_eps)
             # base trajectory: no q-LoRA
             q, sin, cos = tfm._qkv(p_l, h, cfg, None, None, positions[None])
@@ -851,35 +878,25 @@ class PagedExecutor:
             kb_, vb_, ks_, vs_ = self._maybe_quant(kb_, vb_)
             wp_b = jnp.where(valid, wpages_b, self.dump_page)
             wp_r = jnp.where(valid[None], wpages_r, self.dump_page_r)
-            kbp = new_pools.kb.at[li, wp_b, woff].set(kb_[0])
-            vbp = new_pools.vb.at[li, wp_b, woff].set(vb_[0])
-            if self.kv_quant:
-                ksp = new_pools.kb_s.at[li, wp_b, woff].set(ks_[0])
-                vsp = new_pools.vb_s.at[li, wp_b, woff].set(vs_[0])
-            else:
-                ksp, vsp = new_pools.kb_s, new_pools.vb_s
-            krp = new_pools.kr.at[li, wp_r, woff[None]].set(kr_)
-            vrp = new_pools.vr.at[li, wp_r, woff[None]].set(vr_)
-            new_pools = Pools(kbp, vbp, krp, vrp, ksp, vsp)
+            new_pools = write_tokens(
+                new_pools, li, wp_b, wp_r, woff[None], kb_[0], vb_[0],
+                None if ks_ is None else ks_[0],
+                None if vs_ is None else vs_[0], kr_, vr_, cfg.lora.rank)
+            kbp, vbp, _, _, ksp, vsp = new_pools
             # attention over base cache only
             if self.use_paged:
                 attn = kernel_ops.paged_residual_attention_prefill(
-                    q, kbp[li], vbp[li], None, None, None, None,
+                    q, kbp, vbp, None, None, None, None,
                     bt_b[None], None, start[None],
                     (start + n_valid)[None],
                     scale=cfg.resolved_head_dim ** -0.5,
                     window=cfg.sliding_window, rope_theta=cfg.rope_theta,
                     use_rope=cfg.use_rope,
-                    kb_scale=ksp[li] if self.kv_quant else None,
-                    vb_scale=vsp[li] if self.kv_quant else None)
+                    kb_scale=ksp, vb_scale=vsp, layer=li)
             else:
                 w = bt_b.shape[0] * self.page
-                if self.kv_quant:
-                    kc = self._dq_gather(kbp[li], ksp[li], bt_b[None], 1, w)
-                    vc = self._dq_gather(vbp[li], vsp[li], bt_b[None], 1, w)
-                else:
-                    kc = kbp[li][bt_b].reshape(1, w, cfg.num_kv_heads, -1)
-                    vc = vbp[li][bt_b].reshape(1, w, cfg.num_kv_heads, -1)
+                kc, vc, _, _ = self._gather_kv(new_pools, li, bt_b[None],
+                                               None)
                 kmask_pos = jnp.arange(w)[None]
                 attn = tfm._attend(q, kc, vc, None, None, None, None,
                                    kmask_pos, (start + n_valid)[None],
@@ -909,10 +926,11 @@ class PagedExecutor:
         key = (chunk_size, len(adapter_ids))
         if key not in self._broadcast_jit:
             self._broadcast_jit[key] = jax.jit(
-                self._prefill_broadcast_fn, donate_argnums=(0,),
+                self._prefill_broadcast_fn, donate_argnums=(2,),
                 static_argnames=("chunk", "n_agents"))
         self.pools = self._broadcast_jit[key](
-            self.pools, toks, jnp.asarray(start, jnp.int32),
+            self.params, self.lora, self.pools, toks,
+            jnp.asarray(start, jnp.int32),
             jnp.asarray(n, jnp.int32),
             jnp.asarray(list(adapter_ids), jnp.int32),
             jnp.asarray(bt_b, jnp.int32), wb, wr,

@@ -179,13 +179,37 @@ def test_rg_lru_matches_oracle(bsz, s, w, bs, bw, dtype):
 # --------------------------------------------------------------------------
 # Paged ResidualAttention decode (block tables via scalar prefetch)
 # --------------------------------------------------------------------------
+def _base_pages(pool):
+    """Storage (1, P, Hkv, page, D) -> page-major (P, page, Hkv, D)."""
+    return jnp.swapaxes(pool[0], 1, 2)
+
+
+def _res_pages(pool, r):
+    """Packed (1, rows, page, G*r) residual pool -> page-major
+    (rows*G, page, r): page id p lives in lanes (p % G)*r ... of row
+    p // G."""
+    pool = pool[0]
+    rows, page, gr = pool.shape
+    g = gr // r
+    return pool.reshape(rows, page, g, r).transpose(0, 2, 1, 3).reshape(
+        rows * g, page, r)
+
+
 def make_paged_inputs(key, *, bsz, hq, hkv, d, r, page, npages, pool,
                       kv_len=None):
+    """Random one-layer pools in the kernels' storage layouts (page-major
+    draws converted by the layout helpers, with the leading layer axis)
+    plus queries, B and block tables."""
+    from repro.kernels import paged_residual_attention as pra
     ks = jax.random.split(key, 8)
-    kb_pool = jax.random.normal(ks[0], (pool, page, hkv, d))
-    vb_pool = jax.random.normal(ks[1], (pool, page, hkv, d))
-    kr_pool = jax.random.normal(ks[2], (pool, page, r)) * 0.3
-    vr_pool = jax.random.normal(ks[3], (pool, page, r)) * 0.3
+    kb_pool = pra.to_base_pool(
+        jax.random.normal(ks[0], (1, pool, page, hkv, d)))
+    vb_pool = pra.to_base_pool(
+        jax.random.normal(ks[1], (1, pool, page, hkv, d)))
+    kr_pool = pra.to_res_pool(
+        jax.random.normal(ks[2], (1, pool, page, r)) * 0.3)
+    vr_pool = pra.to_res_pool(
+        jax.random.normal(ks[3], (1, pool, page, r)) * 0.3)
     q = jax.random.normal(ks[4], (bsz, hq, d))
     b_k = jax.random.normal(ks[5], (bsz, r, hkv * d)) * 0.3
     b_v = jax.random.normal(ks[6], (bsz, r, hkv * d)) * 0.3
@@ -202,13 +226,13 @@ def make_paged_inputs(key, *, bsz, hq, hkv, d, r, page, npages, pool,
 def paged_dense_oracle(q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v,
                        bt, kv_len, *, use_rope=True):
     bsz, hq, d = q.shape
-    page, hkv = kb_pool.shape[1], kb_pool.shape[2]
+    hkv, page = kb_pool.shape[2], kb_pool.shape[3]
     s = bt.shape[1] * page
-    r = kr_pool.shape[-1]
-    kb = kb_pool[bt].reshape(bsz, s, hkv, d)
-    vb = vb_pool[bt].reshape(bsz, s, hkv, d)
-    kr = kr_pool[bt].reshape(bsz, s, r)
-    vr = vr_pool[bt].reshape(bsz, s, r)
+    r = b_k.shape[1]
+    kb = _base_pages(kb_pool)[bt].reshape(bsz, s, hkv, d)
+    vb = _base_pages(vb_pool)[bt].reshape(bsz, s, hkv, d)
+    kr = _res_pages(kr_pool, r)[bt].reshape(bsz, s, r)
+    vr = _res_pages(vr_pool, r)[bt].reshape(bsz, s, r)
     pos = jnp.broadcast_to(jnp.arange(s), (bsz, s))
     if use_rope:
         sin, cos = rope_lib.rope_sincos(pos, d)
@@ -324,13 +348,13 @@ def paged_prefill_dense_oracle(q, kb_pool, vb_pool, kr_pool, vr_pool, b_k,
     """Independent oracle: gather pages -> contiguous views -> the dense
     residual_attention_ref with explicit qpos/kv_len/window masking."""
     bsz, sq, hq, d = q.shape
-    page = kb_pool.shape[1]
+    hkv, page = kb_pool.shape[2], kb_pool.shape[3]
     s = bt.shape[1] * page
-    r = kr_pool.shape[-1]
-    kb = kb_pool[bt].reshape(bsz, s, kb_pool.shape[2], d)
-    vb = vb_pool[bt].reshape(bsz, s, kb_pool.shape[2], d)
-    kr = kr_pool[bt].reshape(bsz, s, r)
-    vr = vr_pool[bt].reshape(bsz, s, r)
+    r = b_k.shape[1]
+    kb = _base_pages(kb_pool)[bt].reshape(bsz, s, hkv, d)
+    vb = _base_pages(vb_pool)[bt].reshape(bsz, s, hkv, d)
+    kr = _res_pages(kr_pool, r)[bt].reshape(bsz, s, r)
+    vr = _res_pages(vr_pool, r)[bt].reshape(bsz, s, r)
     pos = jnp.broadcast_to(jnp.arange(s), (bsz, s))
     sin, cos = rope_lib.rope_sincos(pos, d)
     qpos = start[:, None] + jnp.arange(sq)[None]

@@ -31,6 +31,7 @@ import pytest
 from repro.configs.paper_models import tiny_serving_model
 from repro.core.config import ServeConfig
 from repro.kernels import ops as kernel_ops
+from repro.kernels import paged_residual_attention as pra
 from repro.models import transformer as tfm
 from repro.serving.api import ForkServer
 from repro.serving.sampling import SamplingParams
@@ -47,14 +48,18 @@ QUALITY_TOL = 0.05    # documented int8-vs-fp32 max-abs bound (DESIGN.md §18)
 
 
 def _quant_pools(rng):
-    """Full-precision pools + their int8 quantization (+ residuals)."""
-    kb = jnp.asarray(rng.standard_normal((P, PAGE, HKV, D)), jnp.float32)
-    vb = jnp.asarray(rng.standard_normal((P, PAGE, HKV, D)), jnp.float32)
+    """Full-precision one-layer pools + their int8 quantization (+
+    residuals), drawn page-major and stored in the kernels' pool layouts
+    with the leading layer axis."""
+    kb = jnp.asarray(rng.standard_normal((1, P, PAGE, HKV, D)), jnp.float32)
+    vb = jnp.asarray(rng.standard_normal((1, P, PAGE, HKV, D)), jnp.float32)
     kq, ks = tfm.quantize_kv(kb)
     vq, vs = tfm.quantize_kv(vb)
-    kr = jnp.asarray(rng.standard_normal((P, PAGE, R)), jnp.float32)
-    vr = jnp.asarray(rng.standard_normal((P, PAGE, R)), jnp.float32)
-    return kb, vb, kq, ks, vq, vs, kr, vr
+    kr = jnp.asarray(rng.standard_normal((1, P, PAGE, R)), jnp.float32)
+    vr = jnp.asarray(rng.standard_normal((1, P, PAGE, R)), jnp.float32)
+    base, scale = pra.to_base_pool, pra.to_scale_pool
+    return (base(kb), base(vb), base(kq), scale(ks), base(vq), scale(vs),
+            pra.to_res_pool(kr), pra.to_res_pool(vr))
 
 
 def _tables(rng, bsz):

@@ -134,16 +134,22 @@ def test_mixed_plan_flag():
 
 # ------------------------------------------- unified-grid kernel oracle
 def _rand_mixed_inputs(key, *, window):
-    """Random pools + a 3-row batch mixing a decode row (q_len=1), a full
-    prefill chunk and a q_len=0 padding row."""
+    """Random one-layer pools (with the leading layer axis) + a 3-row
+    batch mixing a decode row (q_len=1), a full prefill chunk and a
+    q_len=0 padding row."""
     page, hkv, g, d, r, npages = 8, 2, 2, 16, 4, 8
     sq = 4
     hq = hkv * g
+    from repro.kernels import paged_residual_attention as pra
     ks = jax.random.split(key, 8)
-    kb = jax.random.normal(ks[0], (npages, page, hkv, d), jnp.float32)
-    vb = jax.random.normal(ks[1], (npages, page, hkv, d), jnp.float32)
-    kr = 0.1 * jax.random.normal(ks[2], (npages, page, r), jnp.float32)
-    vr = 0.1 * jax.random.normal(ks[3], (npages, page, r), jnp.float32)
+    kb = pra.to_base_pool(
+        jax.random.normal(ks[0], (1, npages, page, hkv, d), jnp.float32))
+    vb = pra.to_base_pool(
+        jax.random.normal(ks[1], (1, npages, page, hkv, d), jnp.float32))
+    kr = pra.to_res_pool(
+        0.1 * jax.random.normal(ks[2], (1, npages, page, r), jnp.float32))
+    vr = pra.to_res_pool(
+        0.1 * jax.random.normal(ks[3], (1, npages, page, r), jnp.float32))
     q = jax.random.normal(ks[4], (3, sq, hq, d), jnp.float32)
     b_k = 0.1 * jax.random.normal(ks[5], (3, r, hkv * d), jnp.float32)
     b_v = 0.1 * jax.random.normal(ks[6], (3, r, hkv * d), jnp.float32)

@@ -6,6 +6,7 @@ import pytest
 
 from repro.configs.paper_models import tiny_serving_model
 from repro.core.config import ServeConfig
+from repro.kernels import paged_residual_attention as pra
 from repro.models import transformer as tfm
 from repro.serving.engine import Engine, Request
 from repro.serving.pool import PagePool
@@ -345,7 +346,8 @@ def test_engine_demote_promote_bit_identical(model):
     assert bpages and rpages
     snap_kb = np.asarray(eng.executor.pools.kb[:, bpages])
     snap_vb = np.asarray(eng.executor.pools.vb[:, bpages])
-    snap_kr = np.asarray(eng.executor.pools.kr[:, rpages])
+    rank = cfg.lora.rank
+    snap_kr = np.asarray(pra.res_pages(eng.executor.pools.kr, rpages, rank))
     eng.dual.base.evict(len(bpages))
     eng.dual.residual.evict(len(rpages))
     assert eng.base_pool.demoted_pages >= len(bpages)
@@ -358,7 +360,7 @@ def test_engine_demote_promote_bit_identical(model):
     np.testing.assert_array_equal(
         snap_vb, np.asarray(eng.executor.pools.vb[:, b2]))
     np.testing.assert_array_equal(
-        snap_kr, np.asarray(eng.executor.pools.kr[:, r2]))
+        snap_kr, np.asarray(pra.res_pages(eng.executor.pools.kr, r2, rank)))
     m = eng.metrics()
     assert m["tier_hits"] >= 2 and m["promoted_bytes"] > 0
 
