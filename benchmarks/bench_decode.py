@@ -11,9 +11,9 @@ Method: for each (mode, path, ctx) cell, one ForkServer with a FIXED
 ``max_pages_per_req`` (so ``smax`` is identical across ctx values) runs the
 same fork twice — the first pass builds the cache and compiles every
 bucket, the second is a full prefix hit, i.e. a pure-decode run — and the
-cell's cost is the delta of the engine's step-phase wall-clock metrics
-(``decode_ms + sync_ms``, the satellite of the same PR) over the delta of
-decode steps.
+cell's cost is the delta of the engine's host time in ``engine.step``
+(``span_ns``, which includes the step's blocking sync) over the delta of
+engine steps.
 
 Emits CSV rows (benchmarks.run harness format) AND writes
 ``BENCH_decode.json`` — the start of this repo's recorded perf trajectory.
@@ -66,9 +66,9 @@ def _measure_cell(mode: str, paged: bool, ctx: int, knobs: Dict) -> Dict:
             out = server.wait([sess.fork(1, instr, sp)])[0]
             m1 = server.metrics()
             assert out.tokens == warm.tokens, "warm/measured runs diverged"
-            steps = m1["decode_steps"] - m0["decode_steps"]
-            ms = (m1["decode_ms"] - m0["decode_ms"] +
-                  m1["sync_ms"] - m0["sync_ms"])
+            steps = m1["steps"] - m0["steps"]
+            ms = (m1["span_ns"].get("engine.step", 0) -
+                  m0["span_ns"].get("engine.step", 0)) / 1e6
             per_step_ms.append(ms / max(1, steps))
     return {
         "mode": mode,
@@ -79,6 +79,13 @@ def _measure_cell(mode: str, paged: bool, ctx: int, knobs: Dict) -> Dict:
         "us_per_decode_step": min(per_step_ms) * 1e3,
         "decode_jit_variants": m1["decode_jit_variants"],
     }
+
+
+# what a row's number is: BENCH_decode.json files written without
+# this key timed the decode call plus its sync only, so their
+# us_per_decode_step is not comparable with these
+MEASURES = ("us_per_decode_step: host time of engine.step (admission, "
+            "planning, the executor call, its sync, commit) per step")
 
 
 def run(smoke: bool) -> Dict:
@@ -116,7 +123,7 @@ def run(smoke: bool) -> Dict:
                  f"{ratio:.3f}")
     return {"smoke": smoke, "knobs": {k: list(v) if isinstance(v, tuple)
                                       else v for k, v in knobs.items()},
-            "rows": rows, "summary": summary}
+            "measures": MEASURES, "rows": rows, "summary": summary}
 
 
 def main(argv=None) -> None:

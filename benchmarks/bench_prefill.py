@@ -14,8 +14,10 @@ Method: for each (mode, path, ctx) cell, one ForkServer with a FIXED
 ``max_pages_per_req`` (so ``smax`` is identical across ctx values) prefills
 one warm prompt (compiles the bucketed shapes) and then N DISTINCT fresh
 prompts of the same length (radix misses, so prefill really recomputes);
-the cell's cost is the delta of the engine's ``prefill_ms`` phase metric
-per prompt token, min-of-N against scheduler noise.
+the cell's cost is the delta of the engine's host time in ``engine.step``
+(``span_ns``; the prefill steps and the one decode step that ends the
+request, each with its blocking sync) per prompt token, min-of-N against
+scheduler noise.
 
 Emits CSV rows (benchmarks.run harness format) AND writes
 ``BENCH_prefill.json`` — recorded next to ``BENCH_decode.json`` in the
@@ -55,12 +57,13 @@ def _measure_cell(mode: str, paged: bool, ctx: int, knobs: Dict) -> Dict:
     sp = SamplingParams(max_new_tokens=1)
 
     def one_pass(seed_offset: int) -> float:
-        """Prefill one fresh ctx-length prompt; return Δprefill_ms."""
+        """Prefill one fresh ctx-length prompt; return Δ engine.step ms."""
         prompt = list(rng.integers(0, cfg.vocab_size, ctx))
         m0 = server.metrics()
         out = server.wait([server.generate(1, prompt, sp)])[0]
         assert len(out.tokens) == 1, out
-        return server.metrics()["prefill_ms"] - m0["prefill_ms"]
+        return (server.metrics()["span_ns"].get("engine.step", 0) -
+                m0["span_ns"].get("engine.step", 0)) / 1e6
 
     one_pass(0)                         # warm: compiles the bucket shapes
     per_tok_ms = min(one_pass(i + 1) for i in range(knobs["passes"])) / ctx
@@ -75,6 +78,13 @@ def _measure_cell(mode: str, paged: bool, ctx: int, knobs: Dict) -> Dict:
         "us_per_prompt_token": per_tok_ms * 1e3,
         "fallback_gather_calls": m["fallback_gather_calls"],
     }
+
+
+# what a row's number is: BENCH_prefill.json files written without
+# this key timed the prefill calls' enqueue only, so their
+# us_per_prompt_token is not comparable with these
+MEASURES = ("us_per_prompt_token: host time of engine.step (every "
+            "phase, syncs included) per prompt token")
 
 
 def run(smoke: bool) -> Dict:
@@ -108,7 +118,7 @@ def run(smoke: bool) -> Dict:
             emit(f"prefill.{mode}.{tag}_paged_over_gather", 0, f"{ratio:.3f}")
     return {"smoke": smoke, "knobs": {k: list(v) if isinstance(v, tuple)
                                       else v for k, v in knobs.items()},
-            "rows": rows, "summary": summary}
+            "measures": MEASURES, "rows": rows, "summary": summary}
 
 
 def main(argv=None) -> None:

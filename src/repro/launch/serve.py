@@ -223,10 +223,11 @@ def main() -> None:
                     help="stuck-pump watchdog threshold in seconds for "
                          "--http (0 = disabled)")
     ap.add_argument("--stats", action="store_true",
-                    help="print step-phase wall-clock totals "
-                         "(prefill/decode/sync ms), compiled decode "
-                         "variant count and per-request latency "
-                         "aggregates (TTFT/TPOT p50/p99)")
+                    help="print host ms per engine phase, the live "
+                         "shares of the mixed grid's slots and the decode "
+                         "grid's page walk, compiled decode variant "
+                         "count and per-request latency aggregates "
+                         "(TTFT/TPOT p50/p99)")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
     use_compile_cache()
@@ -326,15 +327,15 @@ def main() -> None:
                   f"host_used_bytes={rep['host_used_bytes']} "
                   f"preemptions={rep['preemptions']}")
         if args.stats:
-            per_step = rep["decode_ms"] / max(1, rep["decode_steps"])
             print(f"kernels={'paged' if rep['use_paged_kernel'] else 'gather'}"
-                  f" prefill_ms={rep['prefill_ms']:.1f} "
-                  f"decode_ms={rep['decode_ms']:.1f} "
-                  f"sync_ms={rep['sync_ms']:.1f} "
+                  f" {_phases(rep)} "
+                  f"mixed_slot_share={_pct(rep['mixed_slot_share'])} "
+                  f"decode_walk_share={_pct(rep['decode_walk_share'])} "
                   f"decode_steps={rep['decode_steps']} "
-                  f"decode_ms_per_step={per_step:.2f} "
                   f"decode_jit_variants={rep['decode_jit_variants']} "
                   f"fallback_gather_calls={rep['fallback_gather_calls']}")
+            for path, c in sorted(rep["executor_calls"].items()):
+                print(_exec_path(path, c))
             batching = ("mixed" if rep["mixed_batching"]
                         else "phase-separated")
             print(f"batching={batching} "
@@ -366,6 +367,41 @@ def main() -> None:
                   f"watchdog_trips={em['watchdog_trips']} "
                   f"draining={em['draining']} "
                   f"faults_fired={em['faults_fired']}")
+
+
+def _phases(m) -> str:
+    """Host ms per engine phase (``span_ns``, ``executor_calls``), and per
+    step outside the step's device sync."""
+    ns = m["span_ns"]
+    calls = m["executor_calls"].values()
+    phases = [("step", ns.get("engine.step", 0)),
+              ("admit", ns.get("engine.admit", 0)),
+              ("plan", ns.get("scheduler.plan", 0)),
+              ("prepare", sum(c["prepare_ns"] for c in calls)),
+              ("dispatch", sum(c["dispatch_ns"] for c in calls)),
+              ("sync", ns.get("engine.sync", 0)),
+              ("commit", ns.get("engine.commit", 0))]
+    out = " ".join(f"{k}_ms={v / 1e6:.1f}" for k, v in phases)
+    per_step = m["host_ms_per_step"]
+    return out + (f" host_ms_per_step={per_step:.2f}"
+                  if per_step is not None else "")
+
+
+def _exec_path(path: str, c) -> str:
+    """One executor path's counters: calls, the live share of its query
+    slots and of its page walk, host ms preparing and dispatching."""
+    def share(live, of):
+        return _pct(c[live] / c[of] if c[of] else None)
+
+    return (f"executor[{path}] calls={c['calls']} "
+            f"slot_share={share('live_tokens', 'slots')} "
+            f"walk_share={share('live_pages', 'walked_pages')} "
+            f"prepare_ms={c['prepare_ns'] / 1e6:.1f} "
+            f"dispatch_ms={c['dispatch_ns'] / 1e6:.1f}")
+
+
+def _pct(share) -> str:
+    return "n/a" if share is None else f"{100 * share:.1f}%"
 
 
 if __name__ == "__main__":

@@ -37,6 +37,7 @@ import time
 from collections import deque
 from typing import Deque, Dict, Iterator, List, Optional, Sequence
 
+from repro.serving import trace
 from repro.serving.engine import Engine, Request
 from repro.serving.sampling import GREEDY, SamplingParams
 
@@ -216,9 +217,13 @@ class AgentSession:
         if self._closed:
             raise RuntimeError("fork() on a closed AgentSession")
         self.forks += 1
-        return self._server.generate(
-            adapter_id, self.context + list(instruction_tokens),
-            sampling=sampling, tenant=self.tenant, deadline_s=deadline_s)
+        with trace.span("api.fork") as sp:
+            handle = self._server.generate(
+                adapter_id, self.context + list(instruction_tokens),
+                sampling=sampling, tenant=self.tenant,
+                deadline_s=deadline_s)
+            sp.set_metadata(rid=handle.rid)
+        return handle
 
     def close(self) -> None:
         """Drop the session pin; the context becomes evictable again."""
@@ -272,9 +277,10 @@ class ForkServer:
         req = Request(rid=next(self._rids), adapter_id=adapter_id,
                       prompt=list(context_tokens), max_new_tokens=0,
                       is_context=True, arrival=time.time(), tenant=tenant)
-        self.engine.submit(req)
-        while req.state != "done":
-            self.poll()
+        with trace.span("api.session", rid=req.rid):
+            self.engine.submit(req)
+            while req.state != "done":
+                self.poll()
         if req.error:
             raise RuntimeError(f"session context failed: {req.error}")
         pin = self.engine.pin_prefix(req.prompt, adapter_id, tenant=tenant)
@@ -294,14 +300,16 @@ class ForkServer:
         long after arrival finishes with ``finish_reason="timeout"``
         instead of waiting forever (DESIGN.md §15)."""
         sp = sampling if sampling is not None else GREEDY
-        req = Request(rid=next(self._rids), adapter_id=adapter_id,
-                      prompt=list(prompt_tokens),
-                      max_new_tokens=sp.max_new_tokens, sampling=sp,
-                      arrival=time.time(), tenant=tenant,
-                      deadline_s=deadline_s)
-        self.engine.submit(req)
-        handle = GenerationHandle(self, req)
-        self._handles[req.rid] = handle
+        rid = next(self._rids)
+        with trace.span("api.generate", rid=rid):
+            req = Request(rid=rid, adapter_id=adapter_id,
+                          prompt=list(prompt_tokens),
+                          max_new_tokens=sp.max_new_tokens, sampling=sp,
+                          arrival=time.time(), tenant=tenant,
+                          deadline_s=deadline_s)
+            self.engine.submit(req)
+            handle = GenerationHandle(self, req)
+            self._handles[req.rid] = handle
         return handle
 
     # ``submit`` is the historical name for the session-less entry point;
@@ -314,14 +322,15 @@ class ForkServer:
         """Advance the engine one step and dispatch new TokenEvents to
         their handles.  Returns the events dispatched by this call."""
         eng = self.engine
-        if eng.waiting or eng.running:
-            eng.step()
-        events: List[TokenEvent] = []
-        for rid, handle in list(self._handles.items()):
-            events.extend(handle._drain_new())
-            if handle._terminal_sent:
-                del self._handles[rid]     # handle keeps its own queue
-        self.events_dispatched += len(events)
+        with trace.span("api.poll"):
+            if eng.waiting or eng.running:
+                eng.step()
+            events: List[TokenEvent] = []
+            for rid, handle in list(self._handles.items()):
+                events.extend(handle._drain_new())
+                if handle._terminal_sent:
+                    del self._handles[rid]  # handle keeps its own queue
+            self.events_dispatched += len(events)
         return events
 
     def wait(self, handles: Optional[Sequence[GenerationHandle]] = None

@@ -40,12 +40,13 @@ import logging
 import os
 import tempfile
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.config import ModelConfig, ServeConfig
 from repro.serving import faults as faults_mod
+from repro.serving import trace
 from repro.serving.executor import PagedExecutor, pool_bytes
 from repro.serving.fairshare import make_policy
 from repro.serving.pool import PagePool
@@ -250,11 +251,6 @@ class Engine:
         self.scheduler = IterationScheduler(sc)
         self.steps = 0
         self.mixed_steps = 0          # iterations with decode AND prefill
-        # bounded window of recent decode batch sizes (diagnostics only);
-        # the EXACT running aggregates live in _decode_batch_sum/_steps so
-        # avg_decode_batch/decode_steps stay exact while a long-lived
-        # server's memory stays O(1) instead of one int per step
-        self.decode_batch_hist = collections.deque(maxlen=512)
         self._decode_batch_sum = 0
         self._decode_steps = 0
         self.preemptions = 0          # demote-under-pressure events
@@ -288,8 +284,7 @@ class Engine:
         self.policy = make_policy(
             sc, probe_hit=self.prefix_hit_fraction,
             pinned_pages=lambda t: self.tenant_pinned_pages.get(t, 0))
-        # admission-wait distribution (ms): bounded window for p50/p99 —
-        # same O(1)-memory pattern as decode_batch_hist
+        # admission-wait distribution (ms): bounded window for p50/p99
         self._admission_waits = collections.deque(maxlen=2048)
         self._no_progress = 0         # consecutive zero-progress steps
         # speculative decoding (DESIGN.md §16): the proposer is always
@@ -308,13 +303,10 @@ class Engine:
         self.peak_base_pages = 0
         self.peak_res_pages = 0
         self.agent_ids_seen = set()
-        # step-phase wall-clock totals (ms).  prefill/decode time the
-        # executor calls (async dispatch + trace/compile); sync times the
-        # blocking device→host reads — ONE per step, not one per chunk —
-        # so benchmark deltas are attributable to a phase (DESIGN.md §12)
-        self.prefill_ms = 0.0
-        self.decode_ms = 0.0
-        self.sync_ms = 0.0
+        # host time per span (engine.step, engine.admit, scheduler.plan,
+        # engine.sync, engine.commit), on the profiler's clock when it
+        # records (serving/trace.py, DESIGN.md §12)
+        self.clock = trace.Clock()
 
     # ------------------------------------------------------------- submit
     def submit(self, req: Request) -> None:
@@ -560,13 +552,25 @@ class Engine:
             spos.append(len(r.output))
         poison = [1 if self.faults.fire("nan_logits", key=r.rid) else 0
                   for r in group] if self.faults.active else None
-        t0 = time.perf_counter()
         next_toks, _, row_ok = self.executor.prefill_batch(
             chunks, starts, aids, btsb, btsr, wbs, wrs, chunk,
             temps=temps, top_ks=tks, top_ps=tps, seeds=seeds, spos=spos,
             poison=poison)
-        self.prefill_ms += (time.perf_counter() - t0) * 1e3
         host_toks = host_ok = None
+        if any(e >= n and r.max_new_tokens > 0
+               for e, n, r in zip(ends, plens, group)):
+            with self.clock.span("engine.sync"):  # one blocking D2H
+                host_toks = np.asarray(next_toks)
+                host_ok = np.asarray(row_ok)
+        with self.clock.span("engine.commit"):
+            self._commit_prefill(group, chunks, ends, plens, host_toks,
+                                 host_ok)
+        return True
+
+    def _commit_prefill(self, group, chunks, ends, plens, host_toks,
+                        host_ok) -> None:
+        """Advance each row of a batched prefill; a row that finished its
+        prompt takes its first token from the step's one sync."""
         for i, r in enumerate(group):
             r.prefill_pos = ends[i]
             r.kv_len = ends[i]
@@ -580,11 +584,6 @@ class Engine:
                 # product — commit it and finish without generating
                 self._finish(r, reason="length")
                 continue
-            if host_toks is None:       # single blocking D2H for the step
-                t0 = time.perf_counter()
-                host_toks = np.asarray(next_toks)
-                host_ok = np.asarray(row_ok)
-                self.sync_ms += (time.perf_counter() - t0) * 1e3
             if not bool(host_ok[i]):
                 # quarantine (DESIGN.md §17): non-finite logits fail THIS
                 # row; co-batched requests proceed untouched
@@ -605,7 +604,6 @@ class Engine:
             # when the decode step consumes it
             if tok in r.params.stop_token_ids:
                 self._finish(r, reason="stop")
-        return True
 
     def _bt(self, pages: Sequence[int]) -> List[int]:
         bt = list(pages)[:self.max_pages_per_req]
@@ -613,9 +611,7 @@ class Engine:
         return bt + [dump] * (self.max_pages_per_req - len(bt))
 
     def _note_decode_batch(self, n: int) -> None:
-        """Record one decode iteration's batch size: bounded window for
-        diagnostics + exact running aggregates for the metrics."""
-        self.decode_batch_hist.append(n)
+        """Record one decode iteration's batch size."""
         self._decode_batch_sum += n
         self._decode_steps += 1
 
@@ -686,15 +682,19 @@ class Engine:
             spos.append(len(r.output))
         poison = [1 if self.faults.fire("nan_logits", key=r.rid) else 0
                   for r in batch] if self.faults.active else None
-        t0 = time.perf_counter()
         next_toks, _, row_ok = self.executor.decode(
             toks, kvl, ids, btb, btr, wpb, wpr, woff, temps=temps,
             top_ks=tks, top_ps=tps, seeds=seeds, spos=spos, poison=poison)
-        self.decode_ms += (time.perf_counter() - t0) * 1e3
-        t0 = time.perf_counter()
-        host_toks = np.asarray(next_toks)   # ONE blocking D2H per step
-        host_ok = np.asarray(row_ok)        # quarantine guard rides it
-        self.sync_ms += (time.perf_counter() - t0) * 1e3
+        with self.clock.span("engine.sync"):
+            host_toks = np.asarray(next_toks)   # ONE blocking D2H per step
+            host_ok = np.asarray(row_ok)        # quarantine guard rides it
+        with self.clock.span("engine.commit"):
+            self._commit_decode(batch, host_toks, host_ok)
+        return True
+
+    def _commit_decode(self, batch, host_toks, host_ok) -> None:
+        """Append each decode row's token; finish rows at stop or length."""
+        page = self.sc.page_size
         for i, r in enumerate(batch):
             if not bool(host_ok[i]):
                 # quarantine (DESIGN.md §17): this row's logits went
@@ -714,7 +714,6 @@ class Engine:
             elif len(r.output) >= r.max_new_tokens + 1 or \
                     r.kv_len + 1 >= self.max_pages_per_req * page:
                 self._finish(r, reason="length")
-        return True
 
     # ------------------------------------------------------------- finish
     def _commit_cache(self, req: Request) -> None:
@@ -1050,7 +1049,6 @@ class Engine:
         rows = plan.rows
         if not rows:
             return False
-        page = self.sc.page_size
         chunks, starts, aids, btb, btr, wbs, wrs = [], [], [], [], [], \
             [], []
         temps, tks, tps, seeds, spos = [], [], [], [], []
@@ -1105,7 +1103,7 @@ class Engine:
             self.mixed_steps += 1
         poison = [1 if self.faults.fire("nan_logits", key=rp.req.rid)
                   else 0 for rp in rows] if self.faults.active else None
-        t0 = time.perf_counter()
+        ns0 = self.executor.counters.host_ns()
         if verify_rows:
             self.spec_steps += 1
             # verify-only plans pad the q tile to pow2(k+1), not the
@@ -1123,25 +1121,29 @@ class Engine:
                 chunks, starts, aids, btb, btr, wbs, wrs, temps=temps,
                 top_ks=tks, top_ps=tps, seeds=seeds, spos=spos,
                 poison=poison)
-        elapsed = (time.perf_counter() - t0) * 1e3
-        # attribute wall clock by token share: a decode-only iteration is
-        # pure decode_ms (bench_decode's deltas stay meaningful), a mixed
-        # one splits proportionally (verify rows count as decode work)
-        dec_toks = sum(rp.q_len for rp in rows if rp.kind != "prefill")
-        dec_frac = dec_toks / max(1, plan.total_tokens)
-        self.decode_ms += elapsed * dec_frac
-        self.prefill_ms += elapsed * (1.0 - dec_frac)
+        elapsed = (self.executor.counters.host_ns() - ns0) / 1e9
         host_toks = greedy_host = nacc_host = host_ok = None
         if any(emit):               # ONE blocking D2H per iteration
-            t0 = time.perf_counter()
-            host_toks = np.asarray(next_toks)
-            host_ok = np.asarray(row_ok)   # quarantine guard rides the
-            if verify_rows:                # step's one sync (§17)
-                greedy_host = np.asarray(greedy_all)
-                nacc_host = np.asarray(n_acc)
-            self.sync_ms += (time.perf_counter() - t0) * 1e3
+            with self.clock.span("engine.sync"):
+                host_toks = np.asarray(next_toks)
+                host_ok = np.asarray(row_ok)   # quarantine guard rides
+                if verify_rows:                # the step's one sync (§17)
+                    greedy_host = np.asarray(greedy_all)
+                    nacc_host = np.asarray(n_acc)
         if n_decode:
             self._note_decode_batch(n_decode)
+        with self.clock.span("engine.commit"):
+            self._commit_rows(rows, emit, host_toks, host_ok, greedy_host,
+                              nacc_host, elapsed)
+        return True
+
+    def _commit_rows(self, rows, emit, host_toks, host_ok, greedy_host,
+                     nacc_host, elapsed: float) -> None:
+        """Commit one mixed iteration's results row by row: tokens of
+        decode and verify rows (``elapsed``: the executor call's seconds,
+        across which a verify row's tokens are stamped), prefill
+        progress, finishes."""
+        page = self.sc.page_size
         step_end = time.time()
         for i, rp in enumerate(rows):
             r = rp.req
@@ -1170,7 +1172,7 @@ class Engine:
                     ctl.update(k, n_ok)
                 # interpolate per-token stamps across the step's wall
                 # clock (multi-token-safe TPOT/streaming)
-                dt = (elapsed / 1e3) / len(committed)
+                dt = elapsed / len(committed)
                 for j, tok in enumerate(committed):
                     r.kv_len += 1
                     ts = step_end - dt * (len(committed) - 1 - j)
@@ -1222,7 +1224,6 @@ class Engine:
             r.token_times.append(step_end)
             if tok in r.params.stop_token_ids:
                 self._finish(r, reason="stop")
-        return True
 
     # ----------------------------------------------------- refuse helpers
     def _refuse(self, req: Request, reason: str, error: str,
@@ -1268,8 +1269,13 @@ class Engine:
     # --------------------------------------------------------------- step
     def step(self) -> None:
         self.steps += 1
-        now = time.time()
-        self.faults.maybe_stall()       # pump_stall site (watchdog food)
+        with self.clock.step("engine.step", self.steps):
+            self._step()
+
+    def _admit(self, now: float) -> Tuple[bool, List[int]]:
+        """Expiry and shedding (or the drain's refusals), admission in
+        policy order, and the preempt trigger.  Returns whether anything
+        moved, and the ids of the requests admitted."""
         progress = False
         if self.draining:
             # drain (§17): stop admission — every queued request gets a
@@ -1285,6 +1291,7 @@ class Engine:
         # admit, in policy order (FIFO = the seed behaviour: strict
         # arrival order, stop at the first request that does not fit)
         blocked = False
+        admitted_rids = []
         while self.waiting and len(self.running) < self.sc.max_batch:
             req = self.policy.select(self.waiting, now)
             if req is None:               # every waiting tenant over budget
@@ -1316,6 +1323,7 @@ class Engine:
                 (req.admitted_at - req.arrival) * 1e3)
             self.policy.on_admit(req, req.admitted_at)
             progress = True
+            admitted_rids.append(req.rid)
             if req.state == "decode" and req.max_new_tokens == 0:
                 # fully-cached context-only request: nothing to compute
                 self._finish(req, reason="length")
@@ -1329,6 +1337,15 @@ class Engine:
                 progress = True
         elif not blocked:
             self._no_admit = 0
+        return progress, admitted_rids
+
+    def _step(self) -> None:
+        now = time.time()
+        self.faults.maybe_stall()       # pump_stall site (watchdog food)
+        with self.clock.span("engine.admit") as sp:
+            progress, admitted = self._admit(now)
+            if admitted and trace.recording():
+                sp.set_metadata(rids=",".join(map(str, admitted)))
         try:
             self.faults.io("executor")    # injected step failure (§17)
             if self.sc.mixed_batching:
@@ -1339,8 +1356,12 @@ class Engine:
                 # runs as one call
                 if self._try_broadcast():
                     progress = True
-                if self._run_mixed(self.scheduler.plan(
-                        self.running, propose=self._propose)):
+                with self.clock.span("scheduler.plan") as sp:
+                    plan = self.scheduler.plan(self.running,
+                                               propose=self._propose)
+                    sp.set_metadata(rows=len(plan.rows),
+                                    tokens=plan.total_tokens)
+                if self._run_mixed(plan):
                     progress = True
             else:
                 # legacy phase-separated loop: one batched prefill call
@@ -1391,6 +1412,36 @@ class Engine:
             self.step()
 
     # ------------------------------------------------------------ metrics
+    def _trace_metrics(self) -> Dict:
+        """``span_ns``: cumulative host ns per span (``trace.Clock``);
+        ``executor_calls``: per executor path, the cumulative counters of
+        ``trace.ExecCounters``; then, None until counted:
+        ``host_ms_per_step`` (``engine.step`` less ``engine.sync``, per
+        step), ``prepare_ms_per_call`` (all paths),
+        ``mixed_slot_share`` (live tokens over batch x query-tile slots
+        of the mixed path) and ``decode_walk_share`` (live pages over the
+        pages the decode grid walks)."""
+        ns = dict(self.clock.ns)
+        paths = {p: dict(c) for p, c in self.executor.counters.paths.items()}
+        empty = dict.fromkeys(trace.EXEC_FIELDS, 0)
+        mixed = paths.get("mixed", empty)
+        decode = paths.get("decode", empty)
+        host = ns.get("engine.step", 0) - ns.get("engine.sync", 0)
+        calls = sum(c["calls"] for c in paths.values())
+        prepare = sum(c["prepare_ns"] for c in paths.values())
+        return {
+            "span_ns": ns,
+            "executor_calls": paths,
+            "host_ms_per_step": (host / self.steps / 1e6 if self.steps
+                                 else None),
+            "prepare_ms_per_call": prepare / calls / 1e6 if calls else None,
+            "mixed_slot_share": (mixed["live_tokens"] / mixed["slots"]
+                                 if mixed["slots"] else None),
+            "decode_walk_share": (decode["live_pages"] /
+                                  decode["walked_pages"]
+                                  if decode["walked_pages"] else None),
+        }
+
     def metrics(self) -> Dict:
         pb = pool_bytes(self.executor.pools)
         page = self.sc.page_size
@@ -1527,10 +1578,10 @@ class Engine:
             "shed": self.shed,
             "tenants": self.policy.snapshot(),
             "tenant_pinned_pages": dict(self.tenant_pinned_pages),
-            # step-phase wall clock + compiled-variant probe (DESIGN.md §12)
-            "prefill_ms": self.prefill_ms,
-            "decode_ms": self.decode_ms,
-            "sync_ms": self.sync_ms,
+            # host time per span, the executor's per-path counters and
+            # the shares read from them (DESIGN.md §12), and the
+            # compiled-variant probe
+            **self._trace_metrics(),
             "decode_steps": self._decode_steps,
             "decode_jit_variants": self.executor.decode_cache_size(),
             "use_paged_kernel": self.executor.use_paged,

@@ -50,6 +50,7 @@ from repro.kernels import ops as kernel_ops
 from repro.kernels import paged_residual_attention as pra
 from repro.models import base
 from repro.models import transformer as tfm
+from repro.serving import trace
 from repro.serving.sampling import sample_tokens
 
 Params = Dict
@@ -58,6 +59,17 @@ Params = Dict
 def _pow2(n: int) -> int:
     """Smallest power of two >= n (>= 1)."""
     return 1 << max(0, n - 1).bit_length()
+
+
+def _per_row(values, default, n: int, bpad: int) -> list:
+    """``values`` (``default`` for each of the ``n`` live rows when None),
+    padded with ``default`` to ``bpad`` rows."""
+    vals = list(values) if values is not None else [default] * n
+    return vals + [default] * (bpad - n)
+
+
+def _i32(values) -> jnp.ndarray:
+    return jnp.asarray(values, jnp.int32)
 
 
 class Pools(NamedTuple):
@@ -160,6 +172,8 @@ class PagedExecutor:
         # the acceptance probe for "zero gather copies" (0 whenever
         # use_paged_kernel=True; surfaced via Engine.metrics())
         self.fallback_gather_calls = 0
+        # per-path shape counters and host time of each call (trace.py)
+        self.counters = trace.ExecCounters()
         res_factor = max(1, cfg.kv_dim // max(cfg.lora.rank, 1))             if self.disagg else 1
         self.num_res_pages = serve_cfg.max_pages * res_factor             if self.disagg else serve_cfg.max_pages
         self.pools = make_pools(cfg, serve_cfg.max_pages,
@@ -455,53 +469,66 @@ class PagedExecutor:
         ``(next_tok, logits, row_ok)``; rows past the live count are
         padding.
         """
-        bsz = len(tokens)
-        assert bsz <= self.sc.max_batch, (bsz, self.sc.max_batch)
-        bpad = min(_pow2(bsz), self.sc.max_batch)
-        if self.use_paged:
-            width = self._bucket_width(max(kvl // self.page + 1
-                                           for kvl in kv_len))
-        else:
-            width = self.max_pages_per_req
-            self.fallback_gather_calls += 1
-        bt_b = [self._pad_table(p, width, self.dump_page)
-                for p in base_tables]
-        bt_r = [self._pad_table(p, width, self.dump_page_r)
-                for p in res_tables]
-        temps = list(temps) if temps is not None else [0.0] * bsz
-        top_ks = list(top_ks) if top_ks is not None else [0] * bsz
-        top_ps = list(top_ps) if top_ps is not None else [1.0] * bsz
-        seeds = list(seeds) if seeds is not None else [0] * bsz
-        spos = list(spos) if spos is not None else [0] * bsz
-        poison = list(poison) if poison is not None else [0] * bsz
-        pad = bpad - bsz
-        tokens = list(tokens) + [0] * pad
-        kv_len = list(kv_len) + [0] * pad
-        adapter_ids = list(adapter_ids) + [0] * pad
-        bt_b += [[self.dump_page] * width] * pad
-        bt_r += [[self.dump_page_r] * width] * pad
-        wpage_b = list(wpage_b) + [self.dump_page] * pad
-        wpage_r = list(wpage_r) + [self.dump_page_r] * pad
-        woff = list(woff) + [0] * pad
-        temps += [0.0] * pad
-        top_ks += [0] * pad
-        top_ps += [1.0] * pad
-        seeds += [0] * pad
-        spos += [0] * pad
-        poison += [0] * pad
-        self.pools, next_tok, logits, row_ok = self._decode(
-            self.params, self.lora, self.pools,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(kv_len, jnp.int32),
-            jnp.asarray(adapter_ids, jnp.int32),
-            jnp.asarray(bt_b, jnp.int32), jnp.asarray(bt_r, jnp.int32),
-            jnp.asarray(wpage_b, jnp.int32), jnp.asarray(wpage_r, jnp.int32),
-            jnp.asarray(woff, jnp.int32),
-            jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32),
-            jnp.asarray(top_ps, jnp.float32), jnp.asarray(seeds, jnp.int32),
-            jnp.asarray(spos, jnp.int32), jnp.asarray(poison, jnp.int32),
-            sampled=any(t > 0 for t in temps))
+        def prepare():
+            bsz = len(tokens)
+            assert bsz <= self.sc.max_batch, (bsz, self.sc.max_batch)
+            bpad = min(_pow2(bsz), self.sc.max_batch)
+            pages = [kvl // self.page + 1 for kvl in kv_len]
+            width = self._table_width(pages)
+            pad = bpad - bsz
+            bt_b = [self._pad_table(p, width, self.dump_page)
+                    for p in base_tables]
+            bt_r = [self._pad_table(p, width, self.dump_page_r)
+                    for p in res_tables]
+            bt_b += [[self.dump_page] * width] * pad
+            bt_r += [[self.dump_page_r] * width] * pad
+            temps_ = _per_row(temps, 0.0, bsz, bpad)
+            args = (
+                _i32(_per_row(tokens, 0, bsz, bpad)),
+                _i32(_per_row(kv_len, 0, bsz, bpad)),
+                _i32(_per_row(adapter_ids, 0, bsz, bpad)),
+                _i32(bt_b), _i32(bt_r),
+                _i32(_per_row(wpage_b, self.dump_page, bsz, bpad)),
+                _i32(_per_row(wpage_r, self.dump_page_r, bsz, bpad)),
+                _i32(_per_row(woff, 0, bsz, bpad)),
+                *self._sampling(temps_, top_ks, top_ps, seeds, spos,
+                                poison, bsz, bpad))
+            return (self._decode, args,
+                    dict(sampled=any(t > 0 for t in temps_)),
+                    dict(rows=bpad, qpad=1, width=width, live_tokens=bsz,
+                         live_pages=sum(pages)))
+
+        self.pools, next_tok, logits, row_ok = self._call("decode", prepare)
         return next_tok, logits, row_ok
+
+    def _sampling(self, temps, top_ks, top_ps, seeds, spos, poison,
+                  n: int, bpad: int):
+        """The per-row sampling and fault-injection arguments of a call,
+        padded to ``bpad`` rows with neutral values (``temps`` already
+        padded)."""
+        return (jnp.asarray(temps, jnp.float32),
+                _i32(_per_row(top_ks, 0, n, bpad)),
+                jnp.asarray(_per_row(top_ps, 1.0, n, bpad), jnp.float32),
+                _i32(_per_row(seeds, 0, n, bpad)),
+                _i32(_per_row(spos, 0, n, bpad)),
+                _i32(_per_row(poison, 0, n, bpad)))
+
+    def _call(self, path: str, prepare):
+        """One executor call on ``path``, under its ``executor.<path>``
+        span.  ``prepare()`` (``executor.prepare``) returns the jitted
+        function, its device and static arguments, and the call's shape
+        (``rows``, ``qpad``, ``width``, ``live_tokens``, ``live_pages``);
+        the call runs under ``executor.dispatch``.  Both spans' host time,
+        and the shape, count on ``path`` (``trace.ExecCounters``)."""
+        with trace.span(f"executor.{path}") as sp:
+            with self.counters.span(path, "prepare"):
+                fn, args, static, shape = prepare()
+            with self.counters.span(path, "dispatch"):
+                out = fn(self.params, self.lora, self.pools, *args, **static)
+            self.counters.count(path, **shape)
+            sp.set_metadata(bpad=shape["rows"], qpad=shape["qpad"],
+                            width=shape["width"])
+        return out
 
     def decode_cache_size(self) -> int:
         """Number of compiled decode variants (bucket coverage probe)."""
@@ -667,28 +694,46 @@ class PagedExecutor:
         ``(next_tok, logits, row_ok)`` — the engine syncs once per step,
         not per chunk.
         """
-        bsz = len(chunks)
-        bpad = self.prefill_plan(bsz)[0]
-        temps = list(temps) if temps is not None else [0.0] * bsz
-        top_ks = list(top_ks) if top_ks is not None else [0] * bsz
-        top_ps = list(top_ps) if top_ps is not None else [1.0] * bsz
-        seeds = list(seeds) if seeds is not None else [0] * bsz
-        spos = list(spos) if spos is not None else [0] * bsz
-        poison = list(poison) if poison is not None else [0] * bsz
-        if self.use_paged:
-            # prefill width bucketing (§13): tables cover the batch's
-            # largest post-chunk kv extent, bucketed like decode widths
-            w = self._bucket_width(max(
-                -(-(starts[i] + len(chunks[i])) // self.page)
-                for i in range(bsz)))
-        else:
-            w = self.max_pages_per_req
+        def prepare():
+            return self._prepare_rows(
+                self.prefill_plan(len(chunks))[0], chunk_size, chunks,
+                starts, adapter_ids, base_tables, res_tables, wpages_b,
+                wpages_r, temps, top_ks, top_ps, seeds, spos, poison)
+
+        self.pools, next_tok, logits, row_ok = self._call("prefill", prepare)
+        return next_tok, logits, row_ok
+
+    def _row_pages(self, chunks, starts) -> List[int]:
+        """Pages each row reaches once its chunk is written."""
+        return [-(-(s + len(c)) // self.page) for c, s in zip(chunks, starts)]
+
+    def _table_width(self, pages: Sequence[int]) -> int:
+        """Block-table width of a call whose rows reach ``pages``: the
+        bucket of the largest on the paged path, every page of a request
+        on the gather path."""
+        if not self.use_paged:
             self.fallback_gather_calls += 1
+            return self.max_pages_per_req
+        return self._bucket_width(max(pages))
+
+    def _prepare_rows(self, bpad: int, qpad: int, chunks, starts,
+                      adapter_ids, base_tables, res_tables, wpages_b,
+                      wpages_r, temps, top_ks, top_ps, seeds, spos, poison,
+                      **static):
+        """A prefill or mixed call for :meth:`_call`: ``len(chunks)`` rows,
+        each padded to ``qpad`` tokens (pad columns write to the dump
+        page) with block tables cropped/padded to the call's width, then
+        padding rows to ``bpad`` (q_len 0, every write to the dump)."""
+        n = len(chunks)
+        # prefill width bucketing (§13): tables cover the batch's largest
+        # post-chunk kv extent, bucketed like decode widths
+        pages = self._row_pages(chunks, starts)
+        w = self._table_width(pages)
         toks, nvalid, wb, wr, btb, btr = [], [], [], [], [], []
         for i in range(bpad):
-            if i < bsz:
+            if i < n:
                 row = list(chunks[i])
-                pad = chunk_size - len(row)
+                pad = qpad - len(row)
                 toks.append(row + [0] * pad)
                 nvalid.append(len(row))
                 wb.append(list(wpages_b[i]) + [self.dump_page] * pad)
@@ -697,34 +742,25 @@ class PagedExecutor:
                                            self.dump_page))
                 btr.append(self._pad_table(res_tables[i], w,
                                            self.dump_page_r))
-            else:               # padding row: all writes go to the dump
-                toks.append([0] * chunk_size)
+            else:
+                toks.append([0] * qpad)
                 nvalid.append(0)
-                wb.append([self.dump_page] * chunk_size)
-                wr.append([self.dump_page_r] * chunk_size)
+                wb.append([self.dump_page] * qpad)
+                wr.append([self.dump_page_r] * qpad)
                 btb.append([self.dump_page] * w)
                 btr.append([self.dump_page_r] * w)
-        pad = bpad - bsz
-        starts = list(starts) + [0] * pad
-        adapter_ids = list(adapter_ids) + [0] * pad
-        temps += [0.0] * pad
-        top_ks += [0] * pad
-        top_ps += [1.0] * pad
-        seeds += [0] * pad
-        spos += [0] * pad
-        poison += [0] * pad
-        self.pools, next_tok, logits, row_ok = self._prefill(
-            self.params, self.lora, self.pools,
-            jnp.asarray(toks, jnp.int32),
-            jnp.asarray(starts, jnp.int32), jnp.asarray(nvalid, jnp.int32),
-            jnp.asarray(adapter_ids, jnp.int32),
-            jnp.asarray(btb, jnp.int32), jnp.asarray(btr, jnp.int32),
-            jnp.asarray(wb, jnp.int32), jnp.asarray(wr, jnp.int32),
-            jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32),
-            jnp.asarray(top_ps, jnp.float32), jnp.asarray(seeds, jnp.int32),
-            jnp.asarray(spos, jnp.int32), jnp.asarray(poison, jnp.int32),
-            chunk=chunk_size, sampled=any(t > 0 for t in temps))
-        return next_tok, logits, row_ok
+        temps = _per_row(temps, 0.0, n, bpad)
+        args = (_i32(toks), _i32(_per_row(starts, 0, n, bpad)),
+                _i32(nvalid), _i32(_per_row(adapter_ids, 0, n, bpad)),
+                _i32(btb), _i32(btr), _i32(wb), _i32(wr),
+                *self._sampling(temps, top_ks, top_ps, seeds, spos, poison,
+                                n, bpad))
+        return (self._prefill, args,
+                dict(chunk=qpad, sampled=any(t > 0 for t in temps),
+                     **static),
+                dict(rows=bpad, qpad=qpad, width=w,
+                     live_tokens=sum(len(c) for c in chunks),
+                     live_pages=sum(pages)))
 
     # ------------------------------------------------------- mixed batch
     def mixed_step(self, chunks, starts, adapter_ids, base_tables,
@@ -765,74 +801,26 @@ class PagedExecutor:
                 [s % self.page for s in starts], temps=temps,
                 top_ks=top_ks, top_ps=top_ps, seeds=seeds, spos=spos,
                 poison=poison)
-        # shape-bucket with FLOORS, not just pow2: which rows (and which
-        # chunk lengths) coincide in a plan is timing-sensitive, so
-        # bucketing purely by pow2(bsz)/pow2(qmax) sprays one compiled
-        # variant per batch/chunk combination the schedule happens to
-        # produce — and each stray compile is a multi-second stall in the
-        # serving loop.  Flooring the batch at the steady-state size and
-        # the q tile at the prefill chunk cap collapses both axes to one
-        # or two stable buckets; pad rows/columns carry q_len 0 (or sit
-        # past a row's q_len) and are skipped by the kernels' live/mask
-        # conditions.
-        qfloor = qfloor if qfloor > 0 else min(self.sc.max_prefill_tokens,
-                                               32)
-        qpad = _pow2(max(qmax, qfloor))
-        bpad = _pow2(max(bsz, min(self.sc.max_batch, 4)))
-        temps = list(temps) if temps is not None else [0.0] * bsz
-        top_ks = list(top_ks) if top_ks is not None else [0] * bsz
-        top_ps = list(top_ps) if top_ps is not None else [1.0] * bsz
-        seeds = list(seeds) if seeds is not None else [0] * bsz
-        spos = list(spos) if spos is not None else [0] * bsz
-        poison = list(poison) if poison is not None else [0] * bsz
-        if self.use_paged:
-            w = self._bucket_width(max(
-                -(-(starts[i] + len(chunks[i])) // self.page)
-                for i in range(bsz)))
-        else:
-            w = self.max_pages_per_req
-            self.fallback_gather_calls += 1
-        toks, nvalid, wb, wr, btb, btr = [], [], [], [], [], []
-        for i in range(bpad):
-            if i < bsz:
-                row = list(chunks[i])
-                pad = qpad - len(row)
-                toks.append(row + [0] * pad)
-                nvalid.append(len(row))
-                wb.append(list(wpages_b[i]) + [self.dump_page] * pad)
-                wr.append(list(wpages_r[i]) + [self.dump_page_r] * pad)
-                btb.append(self._pad_table(base_tables[i], w,
-                                           self.dump_page))
-                btr.append(self._pad_table(res_tables[i], w,
-                                           self.dump_page_r))
-            else:               # padding row: q_len 0, writes to the dump
-                toks.append([0] * qpad)
-                nvalid.append(0)
-                wb.append([self.dump_page] * qpad)
-                wr.append([self.dump_page_r] * qpad)
-                btb.append([self.dump_page] * w)
-                btr.append([self.dump_page_r] * w)
-        pad = bpad - bsz
-        starts = list(starts) + [0] * pad
-        adapter_ids = list(adapter_ids) + [0] * pad
-        temps += [0.0] * pad
-        top_ks += [0] * pad
-        top_ps += [1.0] * pad
-        seeds += [0] * pad
-        spos += [0] * pad
-        poison += [0] * pad
-        out = self._prefill(
-            self.params, self.lora, self.pools,
-            jnp.asarray(toks, jnp.int32),
-            jnp.asarray(starts, jnp.int32), jnp.asarray(nvalid, jnp.int32),
-            jnp.asarray(adapter_ids, jnp.int32),
-            jnp.asarray(btb, jnp.int32), jnp.asarray(btr, jnp.int32),
-            jnp.asarray(wb, jnp.int32), jnp.asarray(wr, jnp.int32),
-            jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32),
-            jnp.asarray(top_ps, jnp.float32), jnp.asarray(seeds, jnp.int32),
-            jnp.asarray(spos, jnp.int32), jnp.asarray(poison, jnp.int32),
-            chunk=qpad, sampled=any(t > 0 for t in temps), unified=True,
-            verify=verify)
+        def prepare():
+            # shape-bucket with FLOORS, not just pow2: which rows (and which
+            # chunk lengths) coincide in a plan is timing-sensitive, so
+            # bucketing purely by pow2(bsz)/pow2(qmax) sprays one compiled
+            # variant per batch/chunk combination the schedule happens to
+            # produce — and each stray compile is a multi-second stall in
+            # the serving loop.  Flooring the batch at the steady-state size
+            # and the q tile at the prefill chunk cap collapses both axes to
+            # one or two stable buckets; pad rows/columns carry q_len 0 (or
+            # sit past a row's q_len) and are skipped by the kernels'
+            # live/mask conditions.
+            qpad = _pow2(max(qmax, qfloor if qfloor > 0 else min(
+                self.sc.max_prefill_tokens, 32)))
+            bpad = _pow2(max(bsz, min(self.sc.max_batch, 4)))
+            return self._prepare_rows(
+                bpad, qpad, chunks, starts, adapter_ids, base_tables,
+                res_tables, wpages_b, wpages_r, temps, top_ks, top_ps, seeds,
+                spos, poison, unified=True, verify=verify)
+
+        out = self._call("verify" if verify else "mixed", prepare)
         self.pools = out[0]
         return tuple(out[1:])
 
@@ -910,17 +898,6 @@ class PagedExecutor:
 
     def prefill_broadcast(self, tokens, start, adapter_ids, bt_b,
                           wpages_b, wpages_r_list, chunk_size):
-        n = len(tokens)
-        pad = chunk_size - n
-        if self.use_paged:
-            bt_b = self._pad_table(bt_b, self._bucket_width(
-                -(-(start + n) // self.page)), self.dump_page)
-        else:
-            self.fallback_gather_calls += 1
-        toks = jnp.asarray(list(tokens) + [0] * pad, jnp.int32)
-        wb = jnp.asarray(list(wpages_b) + [self.dump_page] * pad, jnp.int32)
-        wr = jnp.asarray([list(w) + [self.dump_page_r] * pad
-                          for w in wpages_r_list], jnp.int32)
         if not hasattr(self, "_broadcast_jit"):
             self._broadcast_jit = {}
         key = (chunk_size, len(adapter_ids))
@@ -928,10 +905,21 @@ class PagedExecutor:
             self._broadcast_jit[key] = jax.jit(
                 self._prefill_broadcast_fn, donate_argnums=(2,),
                 static_argnames=("chunk", "n_agents"))
-        self.pools = self._broadcast_jit[key](
-            self.params, self.lora, self.pools, toks,
-            jnp.asarray(start, jnp.int32),
-            jnp.asarray(n, jnp.int32),
-            jnp.asarray(list(adapter_ids), jnp.int32),
-            jnp.asarray(bt_b, jnp.int32), wb, wr,
-            chunk=chunk_size, n_agents=len(adapter_ids))
+
+        def prepare():
+            n = len(tokens)
+            pad = chunk_size - n
+            pages = self._row_pages([tokens], [start])
+            bt = self._pad_table(bt_b, self._table_width(pages),
+                                 self.dump_page)
+            args = (_i32(list(tokens) + [0] * pad), _i32(start), _i32(n),
+                    _i32(list(adapter_ids)), _i32(bt),
+                    _i32(list(wpages_b) + [self.dump_page] * pad),
+                    _i32([list(w) + [self.dump_page_r] * pad
+                          for w in wpages_r_list]))
+            return (self._broadcast_jit[key], args,
+                    dict(chunk=chunk_size, n_agents=len(adapter_ids)),
+                    dict(rows=1, qpad=chunk_size, width=len(bt),
+                         live_tokens=n, live_pages=pages[0]))
+
+        self.pools = self._call("broadcast", prepare)

@@ -6,7 +6,8 @@ Covers the shape-policy and phasing properties of the paged hot path:
     retraces);
   * batched prefill produces the same results as the seed's one-request-
     per-step chunking (implicitly: every test in the suite runs on it);
-  * step-phase wall-clock metrics are populated.
+  * host time per engine phase and the executor's per-path counters are
+    populated.
 
 Paged-vs-gather token parity lives in tests/test_parity_matrix.py — the
 canonical cross-mode gate over {mode} x {paged, gather} x {attention
@@ -73,9 +74,9 @@ def test_decode_jit_variants_logarithmic(model):
 
 
 def test_phase_metrics_populated(model):
-    """Step-phase wall-clock metrics: prefill/decode both ran, and the
-    per-chunk host sync is gone — sync happens once per step, so sync_ms
-    exists but the counters are all finite and non-negative."""
+    """Host time per engine phase and per executor path: the prompt ran
+    through the mixed path and the tokens through the decode path, and
+    the step's one sync happens once per step, not once per chunk."""
     server, cfg = make_server(model, "forkkv")
     rng = np.random.default_rng(2)
     h = server.generate(1, list(rng.integers(0, cfg.vocab_size, 40)),
@@ -83,9 +84,17 @@ def test_phase_metrics_populated(model):
     out = server.wait([h])[0]
     assert len(out.tokens) == 4
     m = server.metrics()
-    assert m["prefill_ms"] > 0
-    assert m["decode_ms"] > 0
-    assert m["sync_ms"] >= 0
+    ns = m["span_ns"]
+    for phase in ("engine.step", "engine.admit", "scheduler.plan",
+                  "engine.sync", "engine.commit"):
+        assert ns[phase] > 0, phase
+    paths = m["executor_calls"]
+    assert paths["mixed"]["calls"] == 1
+    assert paths["decode"]["calls"] >= 4
+    for c in paths.values():
+        assert c["prepare_ns"] > 0 and c["dispatch_ns"] > 0
+    assert sum(c["calls"] for c in paths.values()) == m["steps"]
+    assert m["host_ms_per_step"] > 0
     assert m["decode_steps"] >= 4
 
 
