@@ -247,6 +247,11 @@ def paged_dense_oracle(q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v,
 @pytest.mark.parametrize("bsz,hq,hkv,d,r,page,npages,pool", [
     (3, 8, 2, 64, 16, 16, 8, 64),     # GQA group 4
     (2, 4, 4, 128, 8, 32, 4, 32),     # MHA, bigger pages, rank 8
+    # blocked walk: 16 pages a step, a 32-page table is two blocks; rows
+    # end at 512 / 256 (a block edge) / 170 tokens, so live blocks differ
+    (3, 8, 2, 64, 16, 16, 32, 64),
+    (2, 8, 1, 64, 8, 8, 64, 96),      # MQA, rank 8, 8-token pages: 2 x 32
+    (2, 4, 4, 64, 64, 16, 16, 32),    # MHA (g = 1), rank 64, width == block
 ])
 def test_paged_decode_matches_dense_oracle(bsz, hq, hkv, d, r, page,
                                            npages, pool):
@@ -321,15 +326,31 @@ def test_paged_decode_base_only_variant():
 
 def test_paged_decode_ragged_kv_len_page_skip():
     """Per-request page skipping: rows whose kv_len covers 1 page out of a
-    wide table (the clamped index maps + pl.when guard) must still match
-    the oracle exactly — including the kv_len=1 degenerate row."""
+    wide table (dead blocks skipped, the last live block's tail masked)
+    must still match the oracle exactly — including the kv_len=1
+    degenerate row."""
+    _check_ragged_decode(8, (1, 16, 19, 128))
+
+
+@pytest.mark.parametrize("npages,kv_len", [
+    # 24-page table: 12 pages (192 tokens) a block; rows end at 1, at the
+    # block edge, 3 tokens into the second block, and at the table's end
+    (24, (1, 192, 195, 384)),
+    # 64-page table, four 16-page blocks: 1, 2, 3 and 4 live blocks
+    (64, (200, 300, 700, 1024)),
+])
+def test_paged_decode_ragged_kv_len_page_skip_blocked(npages, kv_len):
+    """The same across several blocks of the blocked walk."""
+    _check_ragged_decode(npages, kv_len)
+
+
+def _check_ragged_decode(npages, kv_len):
     from repro.kernels.paged_residual_attention import (
         paged_residual_attention_decode)
-    bsz, hq, hkv, d, r, page, npages, pool = 4, 4, 2, 64, 16, 16, 8, 64
-    s = npages * page
+    bsz, hq, hkv, d, r, page, pool = 4, 4, 2, 64, 16, 16, 64
     inp = make_paged_inputs(jax.random.PRNGKey(3), bsz=bsz, hq=hq, hkv=hkv,
                             d=d, r=r, page=page, npages=npages, pool=pool,
-                            kv_len=[1, page, page + 3, s])
+                            kv_len=list(kv_len))
     q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v, bt, kv_len = inp
     got = paged_residual_attention_decode(
         q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v, bt, bt, kv_len,
@@ -455,17 +476,23 @@ def test_paged_prefill_base_only_variant():
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("window", [5, 32])
-def test_paged_decode_sliding_window_matches_ref(window):
+@pytest.mark.parametrize("window,npages,kv_len", [
+    pytest.param(5, 8, (3, 16, 77, 128), id="5"),
+    pytest.param(32, 8, (3, 16, 77, 128), id="32"),
+    # 48-page table, 16 pages a block: windows that start inside a block
+    # (330 - 40 is page 18), or past a whole dead block (768 - 300)
+    pytest.param(40, 48, (3, 16, 330, 768), id="40-wide"),
+    pytest.param(300, 48, (3, 200, 330, 768), id="300-wide"),
+])
+def test_paged_decode_sliding_window_matches_ref(window, npages, kv_len):
     """SWA decode through the paged kernels (window-clamped page walk +
-    in-page masking) vs the gather mirror, across ragged kv_len including
+    in-block masking) vs the gather mirror, across ragged kv_len including
     windows smaller than one page and kv_len < window."""
     from repro.kernels import ops as kernel_ops
-    bsz, hq, hkv, d, r, page, npages, pool = 4, 8, 2, 64, 16, 16, 8, 64
-    s = npages * page
+    bsz, hq, hkv, d, r, page, pool = 4, 8, 2, 64, 16, 16, 64
     inp = make_paged_inputs(jax.random.PRNGKey(11), bsz=bsz, hq=hq,
                             hkv=hkv, d=d, r=r, page=page, npages=npages,
-                            pool=pool, kv_len=[3, page, 77, s])
+                            pool=pool, kv_len=list(kv_len))
     q, kb_pool, vb_pool, kr_pool, vr_pool, b_k, b_v, bt, kv_len = inp
     kw = dict(scale=d ** -0.5, window=window)
     got = kernel_ops.paged_residual_attention(

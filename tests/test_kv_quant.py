@@ -43,40 +43,51 @@ HQ = 4
 D = 64
 R = 4
 W = 3          # block-table width
+WIDE = 32      # a wide table: two 16-page blocks of the blocked walk
 ATOL_BACKEND = 1e-3   # same int8 pages, fp32 math: accumulation noise only
 QUALITY_TOL = 0.05    # documented int8-vs-fp32 max-abs bound (DESIGN.md §18)
 
 
-def _quant_pools(rng):
+def _quant_pools(rng, pages=P):
     """Full-precision one-layer pools + their int8 quantization (+
     residuals), drawn page-major and stored in the kernels' pool layouts
     with the leading layer axis."""
-    kb = jnp.asarray(rng.standard_normal((1, P, PAGE, HKV, D)), jnp.float32)
-    vb = jnp.asarray(rng.standard_normal((1, P, PAGE, HKV, D)), jnp.float32)
+    kb = jnp.asarray(rng.standard_normal((1, pages, PAGE, HKV, D)),
+                     jnp.float32)
+    vb = jnp.asarray(rng.standard_normal((1, pages, PAGE, HKV, D)),
+                     jnp.float32)
     kq, ks = tfm.quantize_kv(kb)
     vq, vs = tfm.quantize_kv(vb)
-    kr = jnp.asarray(rng.standard_normal((1, P, PAGE, R)), jnp.float32)
-    vr = jnp.asarray(rng.standard_normal((1, P, PAGE, R)), jnp.float32)
+    kr = jnp.asarray(rng.standard_normal((1, pages, PAGE, R)), jnp.float32)
+    vr = jnp.asarray(rng.standard_normal((1, pages, PAGE, R)), jnp.float32)
     base, scale = pra.to_base_pool, pra.to_scale_pool
     return (base(kb), base(vb), base(kq), scale(ks), base(vq), scale(vs),
             pra.to_res_pool(kr), pra.to_res_pool(vr))
 
 
-def _tables(rng, bsz):
-    bt = rng.permutation(P - 1)[: bsz * W].reshape(bsz, W)
+def _tables(rng, bsz, width=W):
+    pages = P if width == W else 2 * bsz * width
+    bt = rng.permutation(pages - 1)[: bsz * width].reshape(bsz, width)
     return jnp.asarray(bt, jnp.int32)
 
 
-@pytest.mark.parametrize("disagg", [True, False],
-                         ids=["disagg", "base-only"])
-def test_int8_decode_backend_parity_and_quality(disagg):
+# (disaggregated, table width); the wide cases walk two page blocks
+WIDTHS = [pytest.param(True, W, id="disagg"),
+          pytest.param(False, W, id="base-only"),
+          pytest.param(True, WIDE, id="disagg-wide"),
+          pytest.param(False, WIDE, id="base-only-wide")]
+
+
+@pytest.mark.parametrize("disagg,width", WIDTHS)
+def test_int8_decode_backend_parity_and_quality(disagg, width):
     rng = np.random.default_rng(0)
-    kb, vb, kq, ks, vq, vs, kr, vr = _quant_pools(rng)
     bsz = 2
+    kb, vb, kq, ks, vq, vs, kr, vr = _quant_pools(
+        rng, P if width == W else 2 * bsz * width)
     q = jnp.asarray(rng.standard_normal((bsz, HQ, D)), jnp.float32)
-    bt_b = _tables(rng, bsz)
-    bt_r = _tables(rng, bsz)
-    kv_len = jnp.asarray([PAGE * W - 3, PAGE + 5], jnp.int32)
+    bt_b = _tables(rng, bsz, width)
+    bt_r = _tables(rng, bsz, width)
+    kv_len = jnp.asarray([PAGE * width - 3, PAGE + 5], jnp.int32)
     if disagg:
         b_k = jnp.asarray(rng.standard_normal((bsz, R, HKV * D)) * 0.1,
                           jnp.float32)
@@ -130,18 +141,19 @@ def test_int8_prefill_backend_parity(disagg):
                                rtol=ATOL_BACKEND)
 
 
-@pytest.mark.parametrize("disagg", [True, False],
-                         ids=["disagg", "base-only"])
-def test_int8_mixed_backend_parity(disagg):
+@pytest.mark.parametrize("disagg,width", WIDTHS)
+def test_int8_mixed_backend_parity(disagg, width):
     """Mixed grid: a decode row (q_len=1) and a prefill row (q_len=chunk)
     share one launch; padding rows are exact zeros on both backends."""
     rng = np.random.default_rng(2)
-    kb, vb, kq, ks, vq, vs, kr, vr = _quant_pools(rng)
     bsz, chunk = 2, 8
+    kb, vb, kq, ks, vq, vs, kr, vr = _quant_pools(
+        rng, P if width == W else 2 * bsz * width)
     q = jnp.asarray(rng.standard_normal((bsz, chunk, HQ, D)), jnp.float32)
-    bt_b = _tables(rng, bsz)
-    bt_r = _tables(rng, bsz)
-    start = jnp.asarray([PAGE + 7, 4], jnp.int32)
+    bt_b = _tables(rng, bsz, width)
+    bt_r = _tables(rng, bsz, width)
+    start = jnp.asarray([PAGE * (1 if width == W else width - 1) + 7, 4],
+                        jnp.int32)
     q_len = jnp.asarray([1, chunk], jnp.int32)
     kv_len = start + q_len
     if disagg:

@@ -133,11 +133,14 @@ def test_mixed_plan_flag():
 
 
 # ------------------------------------------- unified-grid kernel oracle
-def _rand_mixed_inputs(key, *, window):
+def _rand_mixed_inputs(key, *, window, wide=False):
     """Random one-layer pools (with the leading layer axis) + a 3-row
     batch mixing a decode row (q_len=1), a full prefill chunk and a
-    q_len=0 padding row."""
+    q_len=0 padding row.  ``wide``: 64-page tables, two blocks of 32
+    pages for the blocked walk, the rows live in different blocks."""
     page, hkv, g, d, r, npages = 8, 2, 2, 16, 4, 8
+    if wide:
+        npages = 96
     sq = 4
     hq = hkv * g
     from repro.kernels import paged_residual_attention as pra
@@ -153,10 +156,16 @@ def _rand_mixed_inputs(key, *, window):
     q = jax.random.normal(ks[4], (3, sq, hq, d), jnp.float32)
     b_k = 0.1 * jax.random.normal(ks[5], (3, r, hkv * d), jnp.float32)
     b_v = 0.1 * jax.random.normal(ks[6], (3, r, hkv * d), jnp.float32)
-    bt_b = jnp.asarray([[0, 1, 2], [3, 4, 5], [0, 0, 0]], jnp.int32)
-    bt_r = jnp.asarray([[5, 6, 7], [1, 2, 3], [0, 0, 0]], jnp.int32)
+    if wide:
+        rng = np.random.default_rng(3)
+        bt_b = jnp.asarray(rng.integers(0, npages, (3, 64)), jnp.int32)
+        bt_r = jnp.asarray(rng.integers(0, npages, (3, 64)), jnp.int32)
+        start = jnp.asarray([300, 250, 0], jnp.int32)
+    else:
+        bt_b = jnp.asarray([[0, 1, 2], [3, 4, 5], [0, 0, 0]], jnp.int32)
+        bt_r = jnp.asarray([[5, 6, 7], [1, 2, 3], [0, 0, 0]], jnp.int32)
+        start = jnp.asarray([17, 4, 0], jnp.int32)
     q_len = jnp.asarray([1, 4, 0], jnp.int32)       # decode | prefill | pad
-    start = jnp.asarray([17, 4, 0], jnp.int32)
     kv_len = start + q_len
     kw = dict(scale=d ** -0.5, window=window, rope_theta=10_000.0,
               use_rope=True)
@@ -164,15 +173,17 @@ def _rand_mixed_inputs(key, *, window):
             kv_len), kw, q_len
 
 
-@pytest.mark.parametrize("window", [0, 12])
-def test_mixed_kernel_matches_ref_oracle(window):
+@pytest.mark.parametrize("window,wide", [
+    pytest.param(0, False, id="0"), pytest.param(12, False, id="12"),
+    pytest.param(0, True, id="0-wide"), pytest.param(20, True, id="20-wide")])
+def test_mixed_kernel_matches_ref_oracle(window, wide):
     """The Pallas unified grid (interpret mode) must match the XLA mixed
     oracle row for row — including EXACT zeros past each row's q_len,
     the cross-backend determinism the prefill grid never promised."""
     from repro.kernels import paged_residual_attention as pra
     from repro.kernels import ref as ref_mod
     args, kw, q_len = _rand_mixed_inputs(jax.random.PRNGKey(0),
-                                         window=window)
+                                         window=window, wide=wide)
     got = pra.paged_residual_attention_mixed(*args, **kw, interpret=True)
     want = ref_mod.paged_residual_attention_mixed_ref(*args, **kw)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -51,19 +51,19 @@ def one_chip(topo):
     cc.reset_cache()
 
 
-def _args(sharding, *, bsz, sq, int8, base_only):
+def _args(sharding, *, bsz, sq, int8, base_only, hq=HQ, width=WIDTH):
     """ShapeDtypeStructs for one grid call on the last layer of stacked
     pools, as the executor makes it, in the dispatcher's order."""
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     dt = jnp.bfloat16
     bdt = jnp.int8 if int8 else dt
-    q = sds((bsz, HQ, D) if sq is None else (bsz, sq, HQ, D), dt)
+    q = sds((bsz, hq, D) if sq is None else (bsz, sq, hq, D), dt)
     kb = sds((LAYERS, POOL_PAGES, HKV, PAGE, D), bdt)
     rows = pra.res_pool_rows(POOL_PAGES * HKV * D // R, R)
     kr = sds((LAYERS, rows, PAGE, pra.res_group(R) * R), dt)
     bk = sds((bsz, R, HKV * D), dt)
-    bt = sds((bsz, WIDTH), jnp.int32)
+    bt = sds((bsz, width), jnp.int32)
     vec = sds((bsz,), jnp.int32)
     kw = dict(scale=SCALE, layer=LAYERS - 1, interpret=False)
     if int8:
@@ -90,11 +90,26 @@ GRIDS = {
 @pytest.mark.parametrize("name,int8", [
     ("decode", False), ("decode_base", False), ("prefill", False),
     ("prefill_base", False), ("mixed", False), ("mixed_base", False),
-    ("decode_base", True), ("mixed_base", True)])
+    ("decode_base", True), ("mixed_base", True), ("decode", True),
+    ("mixed", True)])
 def test_paged_grid_compiles_for_v5e(one_chip, name, int8):
     fn, sq, bsz, base_only, n_vec = GRIDS[name]
     args, vec, kw = _args(one_chip, bsz=bsz, sq=sq, int8=int8,
                           base_only=base_only)
+    compiled = fn.lower(*args, *([vec] * n_vec), **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["decode", "mixed"])
+def test_blocked_grids_compile_at_cell_shapes(one_chip, name):
+    """The blocked walk at the doc-loop cell's shapes (InternLM2-1.8B's 16
+    query / 8 KV heads, 16 rows, a 256-page table, a 256-token query tile
+    for the mixed grid): 16 pages a step, all heads per decode step, one
+    head per mixed step.  VMEM use only shows at this size."""
+    fn, sq, _, base_only, n_vec = GRIDS[name]
+    sq = None if sq is None else 256
+    args, vec, kw = _args(one_chip, bsz=16, sq=sq, int8=False,
+                          base_only=base_only, hq=16, width=256)
     compiled = fn.lower(*args, *([vec] * n_vec), **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
