@@ -10,8 +10,10 @@ had its first token, and lasts ``seconds``.  In it each agent, a closed-loop
 client, takes its next turn the moment its reply ends.  After the window
 closes, the turns submitted in it are waited for until their first token,
 and then a sample of the turns the window finished is compared with the
-plain reference (``chipbench/reference.py``).  With ``control`` the int8
-control's picks stand in the served tokens' place in that comparison.
+plain reference of the configuration's architecture (``chipbench/arch/``).
+With ``control`` the int8 control's picks stand in the served tokens' place
+in that comparison.  The model is reached through that architecture module
+alone: weights, server configuration, reference and work counts.
 
 Host spans around the calls into each layer (``bench.window``,
 ``bench.poll``, ``bench.submit``, ``bench.exec.decode``,
@@ -27,15 +29,15 @@ import statistics
 import sys
 import tempfile
 import time
-from typing import Dict, List, Optional
+from types import ModuleType
+from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
 
-from chipbench import reference, roofline, spec, trace as trace_mod
+from chipbench import roofline, spec, trace as trace_mod
 from chipbench.traffic import (Traffic, longest_context, pages_for, pow2,
                                shortest_context)
-from chipbench.weights import Dims, dims_of, make_weights
 
 DRAIN_LIMIT_S = 60.0
 GRIDS = ("paged_residual_attention_decode", "paged_residual_attention_mixed")
@@ -179,7 +181,8 @@ class Run:
     records: List[TurnRecord]
     setup_s: float
     phases: Dict[str, float]
-    dims: Dims
+    arch: ModuleType                 # the configuration's architecture
+    dims: Any                        # its sizes, for its own functions
     page: int
     kv_pages: int
     kv_peak_share: float
@@ -283,18 +286,18 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     ``checks``, holds each compared number with its limit."""
     from chipbench import serve
     clock = CompileClock()
-    conf, mix = cell.config, cell.traffic
-    dims = dims_of(conf)
+    conf, mix, arch = cell.config, cell.traffic, cell.arch
+    dims = arch.dims_of(conf)
     phases: Dict[str, float] = {}
     t = time.perf_counter()
     phases["start"] = t - t_start
 
     traffic = Traffic(mix, seed, dims.vocab)
-    params, lora = make_weights(dims, traffic.n_adapters, seed)
+    params, lora = arch.make_weights(dims, traffic.n_adapters, seed)
     phases["weights"] = time.perf_counter() - t
     t = time.perf_counter()
 
-    cfg = serve.model_config(conf, dims)
+    cfg = arch.model_config(conf, dims)
     server, kv_pages = serve.build_server(conf, mix, cfg, params, lora)
     ex = server.engine.executor
     phases["server"] = time.perf_counter() - t
@@ -355,8 +358,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     peak_bytes = int(stats.get("peak_bytes_in_use", -1))
 
     run = Run(t0=t0, t1=t1, records=clients.records, setup_s=setup_s,
-              phases=phases, dims=dims, page=ex.page, kv_pages=kv_pages,
-              kv_peak_share=clients.peak_share, counters=(c0, c1),
+              phases=phases, arch=arch, dims=dims, page=ex.page,
+              kv_pages=kv_pages, kv_peak_share=clients.peak_share,
+              counters=(c0, c1),
               exec_calls=[c for c in log.calls if t0 <= c[0] < t1])
     _free(server)
     del server, clients, sessions, groups, ex, log
@@ -379,8 +383,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         if value is not None:
             metrics[m.name] = {"value": float(value), "unit": m.unit}
 
-    readings = compare(run, traffic, params, lora, dims, conf, mix, seed,
-                       control)
+    readings = compare(run, traffic, params, lora, conf, mix, seed, control)
     limits = conf["limits"]
     gap = readings["control_mean_logit_gap" if control else "mean_logit_gap"]
     checks = {
@@ -427,8 +430,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     return result
 
 
-def compare(run: Run, traffic: Traffic, params, lora, dims: Dims,
-            conf: Dict, mix: Dict, seed: int, control: bool) -> Dict:
+def compare(run: Run, traffic: Traffic, params, lora, conf: Dict,
+            mix: Dict, seed: int, control: bool) -> Dict:
     """Served tokens of a sample of the turns the window finished against
     the reference: how far each served token's logit lies below the
     reference's best, as the mean over the sample (the number compared)
@@ -446,9 +449,9 @@ def compare(run: Run, traffic: Traffic, params, lora, dims: Dims,
     for rec in sample:
         agent = traffic.agents[rec.agent]
         doc = agent.doc if traffic.sessions else []
-        p, c = reference.gaps(params, lora, dims, rec.adapter, rec.prompt,
-                              rec.tokens, doc, s_pad, t_pad,
-                              control=control)
+        p, c = run.arch.gaps(params, lora, run.dims, rec.adapter,
+                             rec.prompt, rec.tokens, doc, s_pad, t_pad,
+                             control=control)
         prog.append(p)
         ctrl.append(c)
     prog = np.concatenate(prog) if prog else np.zeros(0)

@@ -1,6 +1,6 @@
 """A paged grid's roofline share in a traced window (shared by the
-``kernel.*_roofline`` readers)."""
-from chipbench.roofline import grid_work, roofline_share
+``kernel.*_roofline`` readers), from the work the architecture counts."""
+from chipbench.roofline import roofline_share
 
 
 def read_share(run, kind: str, op: str):
@@ -13,7 +13,7 @@ def read_share(run, kind: str, op: str):
     flops = nbytes = 0.0
     for rows in calls:
         live = [r for r in rows if r.q_len > 0]
-        work = grid_work(live, run.dims, run.page)
-        flops += work["flops"] * run.dims.layers
-        nbytes += work["bytes"] * run.dims.layers
+        work = run.arch.grid_work(live, run.dims, run.page, kind)
+        flops += work["flops"]
+        nbytes += work["bytes"]
     return roofline_share(flops, nbytes, seconds, run.peak)

@@ -2,18 +2,18 @@
 
 This is the only module of the benchmark that imports the server
 (``src/repro``): its model and serving configurations, and ``ForkServer``,
-the session/fork API users call.
+the session/fork API users call.  An architecture's ``model_config``
+(``chipbench/arch/``) builds the server's ``ModelConfig`` from the
+program's own entries (``get_config``) and ``LoRAConfig``, taken from here.
 """
 from __future__ import annotations
 
-import dataclasses
 import sys
 from typing import Dict
 
 from chipbench.spec import ROOT
 from chipbench.traffic import (longest_context, pages_for, pow2,
                                working_set_pages)
-from chipbench.weights import Dims
 
 if str(ROOT / "src") not in sys.path:
     sys.path.insert(0, str(ROOT / "src"))
@@ -24,25 +24,8 @@ from repro.kernels import ops as kernel_ops           # noqa: E402
 from repro.serving.api import ForkServer              # noqa: E402
 from repro.serving.sampling import SamplingParams     # noqa: E402
 
-__all__ = ["model_config", "build_server", "kernel_backend", "ForkServer",
-           "SamplingParams"]
-
-
-def model_config(conf: Dict, dims: Dims):
-    """The server's ``ModelConfig`` for a configuration file: the
-    program's architecture entry ``program_arch`` with every size the
-    file states."""
-    base = get_config(conf["program_arch"])
-    return dataclasses.replace(
-        base, name=conf["name"], family="dense", num_layers=dims.layers,
-        d_model=dims.d_model, num_heads=dims.heads,
-        num_kv_heads=dims.kv_heads, head_dim=dims.head_dim, d_ff=dims.d_ff,
-        vocab_size=dims.vocab, rope_theta=dims.rope_theta,
-        norm_eps=dims.norm_eps, sliding_window=0, tie_embeddings=False,
-        frontend=conf["frontend"], num_patches=0, dtype=dims.dtype,
-        mlp_activation="silu", kv_quant="none", num_experts=0,
-        lora=LoRAConfig(rank=dims.rank, alpha=dims.alpha,
-                        targets=("q", "k", "v")))
+__all__ = ["build_server", "kernel_backend", "get_config", "LoRAConfig",
+           "ForkServer", "SamplingParams"]
 
 
 def _serve_config(conf: Dict, mix: Dict, max_pages: int) -> ServeConfig:
